@@ -189,7 +189,6 @@ def budget_table(curves):
 _ROUNDTIME_PROG = textwrap.dedent(
     """
     import os, json, time
-    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import dataclasses
     import jax, jax.numpy as jnp
     from repro.configs import get_arch
@@ -263,6 +262,10 @@ def bench_pp_roundtime(quick=False, emit=print):
     env["PYTHONPATH"] = os.path.join(ROOT, "src") + (
         os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else ""
     )
+    # a CPU simulation on 8 fake devices by design: the child never
+    # competes with this process for an accelerator
+    env["JAX_PLATFORMS"] = "cpu"
+    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     out = subprocess.run(
         [sys.executable, "-c", prog], capture_output=True, text=True,
         env=env, timeout=900,

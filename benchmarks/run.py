@@ -49,6 +49,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import (
     DCGD,
     Diana,
@@ -672,7 +673,7 @@ from repro.launch.distributed import build_train_steps
 from repro.models import init_params, reduced
 
 n_dev = jax.device_count()
-mesh = jax.make_mesh((n_dev, 1), ("data", "model"))
+mesh = topo.make_test_mesh(n_dev, 1)
 arch = get_arch("qwen1.5-0.5b")
 arch = dataclasses.replace(arch, model=reduced(arch.model, layers=2, d_model=64))
 bundle = build_train_steps(
@@ -788,6 +789,7 @@ def main():
     ap.add_argument("--quick", action="store_true")
     ap.add_argument("--only", default=None)
     args = ap.parse_args()
+    enable_compile_cache()
 
     benches = {
         "comm_complexity": bench_comm_complexity,

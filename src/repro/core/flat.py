@@ -7,7 +7,8 @@ a round whose whole point is touching only ζ_Q ≪ d coordinates. This module
 replaces that with a single packed representation:
 
 * :class:`FlatLayout` — a *static* description of how a pytree maps onto one
-  zero-padded ``(nblk, B)`` block buffer (B lane-aligned, default 1024).
+  zero-padded ``(rows, B)`` block buffer (B lane-aligned, default 1024): the
+  ``nblk`` blocks that hold the tree, rounded up to whole row tiles.
   Computed once per parameter structure; pack/unpack are pure reshapes +
   one concatenate/slice, jit/vmap/donate friendly.
 * :class:`FlatEngine` — the fused compress → uplink → decompress-mean
@@ -44,6 +45,11 @@ import numpy as np
 PyTree = Any
 
 DEFAULT_BLOCK = 1024  # 8 × 128 VMEM tile width; must be a power of two
+#: buffer rows come in whole (32, 128) int8 tiles (8 f32 rows, 16 bf16): a
+#: worker stack (n, rows, B) then never splits a tile at a worker boundary,
+#: which the TPU compiler otherwise relayouts at great cost (minutes of
+#: compile and ~10 GB of host memory at a 0.2B-parameter flat width)
+ROW_ALIGN = 32
 
 BACKENDS = ("auto", "pallas", "pallas_interpret", "ref")
 
@@ -77,8 +83,11 @@ class FlatLayout:
     """Precomputed static layout of a pytree over a padded block buffer.
 
     Leaves are concatenated in ``jax.tree.flatten`` order at offsets
-    ``slots[i].offset``; the tail ``padded - d`` entries are structural zeros
-    (DESIGN.md §4.1). Hashable/static: safe to close over in jitted functions.
+    ``slots[i].offset``; every entry past ``d`` is a structural zero
+    (DESIGN.md §4.1). ``nblk`` blocks hold the tree and are what the wire
+    carries; the buffer has ``rows`` ≥ nblk of them, and the rows past nblk
+    are zeros that compress to zeros and book no wire bits.
+    Hashable/static: safe to close over in jitted functions.
     """
 
     treedef: Any
@@ -86,10 +95,12 @@ class FlatLayout:
     d: int          # true dimension Σ leaf sizes
     block: int      # B, lane-aligned power of two
     nblk: int       # number of blocks = ceil(d / B)
+    rows: int       # buffer rows: nblk rounded up to ROW_ALIGN
     dtype: Any      # buffer compute dtype (leaves are cast in/out)
 
     @property
     def padded(self) -> int:
+        """Coordinates the wire accounts for: nblk whole blocks."""
         return self.nblk * self.block
 
 
@@ -107,22 +118,23 @@ def make_layout(
         off += size
     d = off
     nblk = max(1, -(-d // block))
+    rows = -(-nblk // ROW_ALIGN) * ROW_ALIGN
     return FlatLayout(
         treedef=treedef, slots=tuple(slots), d=d, block=block, nblk=nblk,
-        dtype=dtype,
+        rows=rows, dtype=dtype,
     )
 
 
 def pack(layout: FlatLayout, tree: PyTree) -> jax.Array:
-    """Pytree → ``(nblk, B)`` padded buffer (one concatenate, zero pad)."""
+    """Pytree → ``(rows, B)`` padded buffer (one concatenate, zero pad)."""
     leaves = layout.treedef.flatten_up_to(tree)
     flat = jnp.concatenate(
         [jnp.ravel(l).astype(layout.dtype) for l in leaves]
     )
-    pad = layout.padded - layout.d
+    pad = layout.rows * layout.block - layout.d
     if pad:
         flat = jnp.pad(flat, (0, pad))
-    return flat.reshape(layout.nblk, layout.block)
+    return flat.reshape(layout.rows, layout.block)
 
 
 def unpack(layout: FlatLayout, buf: jax.Array) -> PyTree:
@@ -136,7 +148,7 @@ def unpack(layout: FlatLayout, buf: jax.Array) -> PyTree:
 
 
 def pack_stacked(layout: FlatLayout, tree: PyTree) -> jax.Array:
-    """Worker-stacked pytree (leading axis n) → ``(n, nblk, B)``."""
+    """Worker-stacked pytree (leading axis n) → ``(n, rows, B)``."""
     return jax.vmap(lambda t: pack(layout, t))(tree)
 
 
@@ -317,11 +329,11 @@ def nibble_roundtrip(levels: jax.Array, block: int,
     from repro.kernels import quantize
 
     backend = resolve_backend(backend)
-    n, nblk, B = levels.shape
-    assert B == block, f"levels last dim {B} != wire block width {block}"
-    words = quantize.nibble_pack(levels.reshape(n * nblk, B), backend=backend)
-    out = quantize.nibble_unpack(words, B, backend=backend)
-    return out.reshape(n, nblk, B)
+    assert levels.shape[-1] == block, (
+        f"levels last dim {levels.shape[-1]} != wire block width {block}"
+    )
+    words = quantize.nibble_pack(levels, backend=backend)
+    return quantize.nibble_unpack(words, block, backend=backend)
 
 
 def key_to_seed(key: jax.Array) -> jax.Array:

@@ -1,6 +1,6 @@
 """Pallas TPU kernels for the fused server epilogue (DESIGN.md §4.7/§5).
 
-One (nblk, B)-tile HBM sweep finishes a compressed round on the receiving
+One HBM sweep over (R, B) row tiles (kernels/tiling.py) finishes a compressed round on the receiving
 side: dequantize/scatter-mean the worker payloads into the round delta,
 advance the estimator ``g += δ`` and step the iterate ``x −= γ·g`` — three
 passes (dequant-mean kernel + two ``tree.map`` sweeps) collapsed into one
@@ -37,9 +37,11 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
 from . import ref as _ref
+from .quantize import natural_rows, qsgd_rows
+from .randk import scatter_rows
+from .tiling import per_block, row_call
 
 
 def _resolve(backend: str) -> str:
@@ -60,10 +62,24 @@ def _apply(g_new, x, gamma):
 # ---------------------------------------------------------------------------
 
 
-def _delta_epilogue_kernel(d_ref, g_ref, x_ref, gout_ref, xout_ref, *, gamma):
-    g_new = g_ref[...].astype(jnp.float32) + d_ref[...].astype(jnp.float32)
+def _finish(g_new, x_ref, gout_ref, xout_ref, gamma):
     gout_ref[...] = g_new
     xout_ref[...] = _apply(g_new, x_ref[...], gamma).astype(xout_ref.dtype)
+
+
+def _call(kernel, args, x2d, backend):
+    """Row-tiled epilogue call: the last operand is x; outputs (g' f32,
+    x' in x's dtype) of x's shape."""
+    B = x2d.shape[-1]
+    return row_call(
+        kernel, [*args, x2d], [(B, jnp.float32), (B, x2d.dtype)],
+        interpret=(backend == "pallas_interpret"),
+    )
+
+
+def _delta_epilogue_kernel(d_ref, g_ref, x_ref, gout_ref, xout_ref, *, gamma):
+    g_new = g_ref[...].astype(jnp.float32) + d_ref[...].astype(jnp.float32)
+    _finish(g_new, x_ref, gout_ref, xout_ref, gamma)
 
 
 def delta_epilogue(delta2d, g2d, x2d, gamma: float, *, backend: str = "auto"):
@@ -71,39 +87,20 @@ def delta_epilogue(delta2d, g2d, x2d, gamma: float, *, backend: str = "auto"):
     backend = _resolve(backend)
     if backend == "ref":
         return _ref.delta_epilogue_ref(delta2d, g2d, x2d, float(gamma))
-    nblk, B = g2d.shape
-    return pl.pallas_call(
+    return _call(
         functools.partial(_delta_epilogue_kernel, gamma=float(gamma)),
-        grid=(nblk,),
-        in_specs=[
-            pl.BlockSpec((1, B), lambda i: (i, 0)),
-            pl.BlockSpec((1, B), lambda i: (i, 0)),
-            pl.BlockSpec((1, B), lambda i: (i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, B), lambda i: (i, 0)),
-            pl.BlockSpec((1, B), lambda i: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((nblk, B), jnp.float32),
-            jax.ShapeDtypeStruct((nblk, B), x2d.dtype),
-        ],
-        interpret=(backend == "pallas_interpret"),
-    )(delta2d, g2d, x2d)
+        [delta2d, g2d], x2d, backend,
+    )
 
 
-def _mean_epilogue_kernel(gb_ref, x_ref, gout_ref, xout_ref, *, n, gamma):
-    B = x_ref.shape[-1]
+def _mean_epilogue_kernel(gb_ref, x_ref, gout_ref, xout_ref, *, gamma):
+    n = gb_ref.shape[0]
 
     def body(w, acc):
-        return acc + jax.lax.dynamic_index_in_dim(
-            gb_ref[...], w, 0, keepdims=False
-        ).astype(jnp.float32)
+        return acc + gb_ref[w].astype(jnp.float32)
 
-    acc = jax.lax.fori_loop(0, n, body, jnp.zeros((1, B), jnp.float32))
-    g_new = acc / n
-    gout_ref[...] = g_new
-    xout_ref[...] = _apply(g_new, x_ref[...], gamma).astype(xout_ref.dtype)
+    acc = jax.lax.fori_loop(0, n, body, jnp.zeros(x_ref.shape, jnp.float32))
+    _finish(acc / n, x_ref, gout_ref, xout_ref, gamma)
 
 
 def mean_epilogue(gbufs, x2d, gamma: float, *, backend: str = "auto"):
@@ -113,24 +110,10 @@ def mean_epilogue(gbufs, x2d, gamma: float, *, backend: str = "auto"):
     backend = _resolve(backend)
     if backend == "ref":
         return _ref.mean_epilogue_ref(gbufs, x2d, float(gamma))
-    n, nblk, B = gbufs.shape
-    return pl.pallas_call(
-        functools.partial(_mean_epilogue_kernel, n=n, gamma=float(gamma)),
-        grid=(nblk,),
-        in_specs=[
-            pl.BlockSpec((n, 1, B), lambda i: (0, i, 0)),
-            pl.BlockSpec((1, B), lambda i: (i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, B), lambda i: (i, 0)),
-            pl.BlockSpec((1, B), lambda i: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((nblk, B), jnp.float32),
-            jax.ShapeDtypeStruct((nblk, B), x2d.dtype),
-        ],
-        interpret=(backend == "pallas_interpret"),
-    )(gbufs, x2d)
+    return _call(
+        functools.partial(_mean_epilogue_kernel, gamma=float(gamma)),
+        [gbufs], x2d, backend,
+    )
 
 
 # ---------------------------------------------------------------------------
@@ -142,44 +125,31 @@ def mean_epilogue(gbufs, x2d, gamma: float, *, backend: str = "auto"):
 # ---------------------------------------------------------------------------
 
 
-def _trimmed_rows(vals, n, lo, hi):
-    """In-kernel trimmed mean of (n, 1, B) worker values → (1, B) f32.
-    Accumulation order matches ``trimmed_mean_rows_ref`` loop for loop."""
-    x = vals.astype(jnp.float32)
-    iota = jax.lax.broadcasted_iota(jnp.int32, x.shape, 0)
+def _trimmed_rows(b_ref, lo, hi):
+    """In-kernel trimmed mean of the (n, R, B) worker values → (R, B) f32,
+    kept values summed in worker order."""
+    n = b_ref.shape[0]
+    acc = jnp.zeros(b_ref.shape[1:], jnp.float32)
+    for i in range(n):
+        vi = b_ref[i].astype(jnp.float32)
 
-    def rank_body(j, acc):
-        vj = jax.lax.dynamic_index_in_dim(x, j, 0, keepdims=True)
-        lt = (vj < x).astype(jnp.int32)
-        tie = (vj == x).astype(jnp.int32) * (iota > j).astype(jnp.int32)
-        return acc + lt + tie
+        def rank_body(j, rank):
+            vj = b_ref[j].astype(jnp.float32)
+            tie = (vj == vi) & (j < i)
+            return rank + (vj < vi).astype(jnp.int32) + tie.astype(jnp.int32)
 
-    ranks = jax.lax.fori_loop(
-        0, n, rank_body, jnp.zeros(x.shape, jnp.int32)
-    )
-    keep = (ranks >= lo) & (ranks < hi)
-
-    def sum_body(j, acc):
+        rank = jax.lax.fori_loop(0, n, rank_body, jnp.zeros(vi.shape, jnp.int32))
         # select, don't multiply: 0·NaN is NaN and trimming must drop
         # non-finite payload rows (they rank 0 — see the ref docstring)
-        vj = jax.lax.dynamic_index_in_dim(x, j, 0, keepdims=False)
-        kj = jax.lax.dynamic_index_in_dim(keep, j, 0, keepdims=False)
-        return acc + jnp.where(kj, vj, 0.0)
-
-    acc = jax.lax.fori_loop(
-        0, n, sum_body, jnp.zeros(x.shape[1:], jnp.float32)
-    )
+        acc = acc + jnp.where((rank >= lo) & (rank < hi), vi, 0.0)
     return acc / (hi - lo)
 
 
 def _trimmed_delta_kernel(
-    b_ref, g_ref, x_ref, gout_ref, xout_ref, *, n, lo, hi, gamma
+    b_ref, g_ref, x_ref, gout_ref, xout_ref, *, lo, hi, gamma
 ):
-    g_new = g_ref[...].astype(jnp.float32) + _trimmed_rows(
-        b_ref[...], n, lo, hi
-    )
-    gout_ref[...] = g_new
-    xout_ref[...] = _apply(g_new, x_ref[...], gamma).astype(xout_ref.dtype)
+    g_new = g_ref[...].astype(jnp.float32) + _trimmed_rows(b_ref, lo, hi)
+    _finish(g_new, x_ref, gout_ref, xout_ref, gamma)
 
 
 def trimmed_delta_epilogue(bufs, g2d, x2d, gamma: float, lo: int, hi: int, *,
@@ -192,36 +162,16 @@ def trimmed_delta_epilogue(bufs, g2d, x2d, gamma: float, lo: int, hi: int, *,
     if backend == "ref":
         return _ref.trimmed_delta_epilogue_ref(bufs, g2d, x2d, float(gamma),
                                                lo, hi)
-    n, nblk, B = bufs.shape
-    return pl.pallas_call(
+    return _call(
         functools.partial(
-            _trimmed_delta_kernel, n=n, lo=int(lo), hi=int(hi),
-            gamma=float(gamma),
+            _trimmed_delta_kernel, lo=int(lo), hi=int(hi), gamma=float(gamma),
         ),
-        grid=(nblk,),
-        in_specs=[
-            pl.BlockSpec((n, 1, B), lambda i: (0, i, 0)),
-            pl.BlockSpec((1, B), lambda i: (i, 0)),
-            pl.BlockSpec((1, B), lambda i: (i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, B), lambda i: (i, 0)),
-            pl.BlockSpec((1, B), lambda i: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((nblk, B), jnp.float32),
-            jax.ShapeDtypeStruct((nblk, B), x2d.dtype),
-        ],
-        interpret=(backend == "pallas_interpret"),
-    )(bufs, g2d, x2d)
+        [bufs, g2d], x2d, backend,
+    )
 
 
-def _trimmed_sync_kernel(
-    b_ref, x_ref, gout_ref, xout_ref, *, n, lo, hi, gamma
-):
-    g_new = _trimmed_rows(b_ref[...], n, lo, hi)
-    gout_ref[...] = g_new
-    xout_ref[...] = _apply(g_new, x_ref[...], gamma).astype(xout_ref.dtype)
+def _trimmed_sync_kernel(b_ref, x_ref, gout_ref, xout_ref, *, lo, hi, gamma):
+    _finish(_trimmed_rows(b_ref, lo, hi), x_ref, gout_ref, xout_ref, gamma)
 
 
 def trimmed_sync_epilogue(bufs, x2d, gamma: float, lo: int, hi: int, *,
@@ -232,27 +182,12 @@ def trimmed_sync_epilogue(bufs, x2d, gamma: float, lo: int, hi: int, *,
     backend = _resolve(backend)
     if backend == "ref":
         return _ref.trimmed_sync_epilogue_ref(bufs, x2d, float(gamma), lo, hi)
-    n, nblk, B = bufs.shape
-    return pl.pallas_call(
+    return _call(
         functools.partial(
-            _trimmed_sync_kernel, n=n, lo=int(lo), hi=int(hi),
-            gamma=float(gamma),
+            _trimmed_sync_kernel, lo=int(lo), hi=int(hi), gamma=float(gamma),
         ),
-        grid=(nblk,),
-        in_specs=[
-            pl.BlockSpec((n, 1, B), lambda i: (0, i, 0)),
-            pl.BlockSpec((1, B), lambda i: (i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, B), lambda i: (i, 0)),
-            pl.BlockSpec((1, B), lambda i: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((nblk, B), jnp.float32),
-            jax.ShapeDtypeStruct((nblk, B), x2d.dtype),
-        ],
-        interpret=(backend == "pallas_interpret"),
-    )(bufs, x2d)
+        [bufs], x2d, backend,
+    )
 
 
 # ---------------------------------------------------------------------------
@@ -261,75 +196,37 @@ def trimmed_sync_epilogue(bufs, x2d, gamma: float, lo: int, hi: int, *,
 
 
 def _scatter_epilogue_kernel(
-    vals_ref, off_ref, g_ref, x_ref, gout_ref, xout_ref, *, n, gamma
+    vals_ref, off_ref, g_ref, x_ref, gout_ref, xout_ref, *, gamma
 ):
-    vals = vals_ref[...]      # (n, 1, kb)
-    offs = off_ref[...]       # (n, 1, kb)
-    kb = vals.shape[-1]
-    B = g_ref.shape[-1]
-
-    def body(w, acc):
-        off_w = jax.lax.dynamic_index_in_dim(offs, w, 0, keepdims=False)
-        val_w = jax.lax.dynamic_index_in_dim(vals, w, 0, keepdims=False)
-        iota = jax.lax.broadcasted_iota(jnp.int32, (kb, B), 1)
-        onehot = (iota == off_w.reshape(kb, 1)).astype(jnp.float32)
-        return acc + jax.lax.dot_general(
-            val_w.astype(jnp.float32), onehot, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-
-    acc = jax.lax.fori_loop(0, n, body, jnp.zeros((1, B), jnp.float32))
-    g_new = g_ref[...].astype(jnp.float32) + acc / n
-    gout_ref[...] = g_new
-    xout_ref[...] = _apply(g_new, x_ref[...], gamma).astype(xout_ref.dtype)
+    R, B = g_ref.shape
+    n = vals_ref.shape[0]
+    acc = scatter_rows(vals_ref, off_ref, R, B)
+    _finish(g_ref[...].astype(jnp.float32) + acc / n, x_ref, gout_ref,
+            xout_ref, gamma)
 
 
 def scatter_epilogue(values, offsets, g2d, x2d, gamma: float, *,
                      backend: str = "auto"):
     """Seeded-RandK epilogue: payloads (n, nblk, kb) ×2 + g + x → (g', x').
-    The scatter-accumulate (one-hot MXU matmuls) and the g/x update share
-    one grid sweep; per-worker dense trees are never materialized."""
+    The scatter-accumulate and the g/x update share one grid sweep;
+    per-worker dense trees are never materialized."""
     backend = _resolve(backend)
     if backend == "ref":
         return _ref.scatter_epilogue_ref(values, offsets, g2d, x2d,
                                          float(gamma))
-    n, nblk, kb = values.shape
-    B = g2d.shape[-1]
-    return pl.pallas_call(
-        functools.partial(_scatter_epilogue_kernel, n=n, gamma=float(gamma)),
-        grid=(nblk,),
-        in_specs=[
-            pl.BlockSpec((n, 1, kb), lambda i: (0, i, 0)),
-            pl.BlockSpec((n, 1, kb), lambda i: (0, i, 0)),
-            pl.BlockSpec((1, B), lambda i: (i, 0)),
-            pl.BlockSpec((1, B), lambda i: (i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, B), lambda i: (i, 0)),
-            pl.BlockSpec((1, B), lambda i: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((nblk, B), jnp.float32),
-            jax.ShapeDtypeStruct((nblk, B), x2d.dtype),
-        ],
-        interpret=(backend == "pallas_interpret"),
-    )(values.astype(jnp.float32), offsets, g2d, x2d)
+    return _call(
+        functools.partial(_scatter_epilogue_kernel, gamma=float(gamma)),
+        [values.astype(jnp.float32), offsets.astype(jnp.int32), g2d], x2d,
+        backend,
+    )
 
 
 def _qsgd_epilogue_kernel(
-    q_ref, norm_ref, g_ref, x_ref, gout_ref, xout_ref, *, n, s, gamma
+    q_ref, norm_ref, g_ref, x_ref, gout_ref, xout_ref, *, s, gamma
 ):
-    B = g_ref.shape[-1]
-
-    def body(w, acc):
-        qw = jax.lax.dynamic_index_in_dim(q_ref[...], w, 0, keepdims=False)
-        nw = jax.lax.dynamic_index_in_dim(norm_ref[...], w, 0, keepdims=False)
-        return acc + qw.astype(jnp.float32) * (nw[0] / s)
-
-    acc = jax.lax.fori_loop(0, n, body, jnp.zeros((1, B), jnp.float32))
-    g_new = g_ref[...].astype(jnp.float32) + acc / n
-    gout_ref[...] = g_new
-    xout_ref[...] = _apply(g_new, x_ref[...], gamma).astype(xout_ref.dtype)
+    acc = qsgd_rows(q_ref, norm_ref, s)
+    _finish(g_ref[...].astype(jnp.float32) + acc / q_ref.shape[0], x_ref,
+            gout_ref, xout_ref, gamma)
 
 
 def qsgd_epilogue(levels, norms, g2d, x2d, gamma: float, s: int, *,
@@ -342,46 +239,18 @@ def qsgd_epilogue(levels, norms, g2d, x2d, gamma: float, s: int, *,
     if backend == "ref":
         return _ref.qsgd_epilogue_ref(levels, norms, g2d, x2d, float(gamma),
                                       s)
-    n, nblk, B = levels.shape
-    return pl.pallas_call(
-        functools.partial(
-            _qsgd_epilogue_kernel, n=n, s=int(s), gamma=float(gamma)
-        ),
-        grid=(nblk,),
-        in_specs=[
-            pl.BlockSpec((n, 1, B), lambda i: (0, i, 0)),
-            pl.BlockSpec((n, 1), lambda i: (0, i)),
-            pl.BlockSpec((1, B), lambda i: (i, 0)),
-            pl.BlockSpec((1, B), lambda i: (i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, B), lambda i: (i, 0)),
-            pl.BlockSpec((1, B), lambda i: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((nblk, B), jnp.float32),
-            jax.ShapeDtypeStruct((nblk, B), x2d.dtype),
-        ],
-        interpret=(backend == "pallas_interpret"),
-    )(levels, norms, g2d, x2d)
+    return _call(
+        functools.partial(_qsgd_epilogue_kernel, s=int(s), gamma=float(gamma)),
+        [levels, per_block(norms), g2d], x2d, backend,
+    )
 
 
 def _natural_epilogue_kernel(
-    code_ref, scale_ref, g_ref, x_ref, gout_ref, xout_ref, *, n, gamma
+    code_ref, scale_ref, g_ref, x_ref, gout_ref, xout_ref, *, gamma
 ):
-    B = g_ref.shape[-1]
-
-    def body(w, acc):
-        cw = jax.lax.dynamic_index_in_dim(code_ref[...], w, 0, keepdims=False)
-        sw = jax.lax.dynamic_index_in_dim(scale_ref[...], w, 0, keepdims=False)
-        c = cw.astype(jnp.float32)
-        mag = sw[0] * jnp.exp2(-(jnp.abs(c) - 1.0))
-        return acc + jnp.where(c != 0, jnp.sign(c) * mag, 0.0)
-
-    acc = jax.lax.fori_loop(0, n, body, jnp.zeros((1, B), jnp.float32))
-    g_new = g_ref[...].astype(jnp.float32) + acc / n
-    gout_ref[...] = g_new
-    xout_ref[...] = _apply(g_new, x_ref[...], gamma).astype(xout_ref.dtype)
+    acc = natural_rows(code_ref, scale_ref)
+    _finish(g_ref[...].astype(jnp.float32) + acc / code_ref.shape[0], x_ref,
+            gout_ref, xout_ref, gamma)
 
 
 def natural_epilogue(codes, scales, g2d, x2d, gamma: float, *,
@@ -392,23 +261,7 @@ def natural_epilogue(codes, scales, g2d, x2d, gamma: float, *,
     if backend == "ref":
         return _ref.natural_epilogue_ref(codes, scales, g2d, x2d,
                                          float(gamma))
-    n, nblk, B = codes.shape
-    return pl.pallas_call(
-        functools.partial(_natural_epilogue_kernel, n=n, gamma=float(gamma)),
-        grid=(nblk,),
-        in_specs=[
-            pl.BlockSpec((n, 1, B), lambda i: (0, i, 0)),
-            pl.BlockSpec((n, 1), lambda i: (0, i)),
-            pl.BlockSpec((1, B), lambda i: (i, 0)),
-            pl.BlockSpec((1, B), lambda i: (i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, B), lambda i: (i, 0)),
-            pl.BlockSpec((1, B), lambda i: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((nblk, B), jnp.float32),
-            jax.ShapeDtypeStruct((nblk, B), x2d.dtype),
-        ],
-        interpret=(backend == "pallas_interpret"),
-    )(codes, scales, g2d, x2d)
+    return _call(
+        functools.partial(_natural_epilogue_kernel, gamma=float(gamma)),
+        [codes, per_block(scales), g2d], x2d, backend,
+    )

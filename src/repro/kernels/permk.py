@@ -15,8 +15,8 @@ pure uint32 VPU arithmetic, bit-exactly reproduced by
 collision-free (``scatter_accum`` degenerates to assembly; the jnp ref also
 provides a scatter-free inverse-perm gather, ``ref.permk_concat_mean_ref``).
 
-The gather itself is the repo's idiomatic one-hot matmul against an iota
-(kernels/randk.py) so the irregular indices ride the MXU.
+The gather is the lane gather of kernels/tiling.py (one 128-lane vreg slice
+of the row at a time), so values are copied exactly.
 """
 
 from __future__ import annotations
@@ -26,42 +26,34 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 from .randk import murmur_bits
+from .tiling import LANES, Smem, lane_gather, lanes, stack_call, tile_rows
 
 
-def _permk_workers_kernel(
-    seed_ref, x_ref, vals_ref, off_ref, *, nblk: int, n: int
-):
-    i = pl.program_id(0)          # global block id over n·nblk
-    w = i // nblk                 # worker
-    b = i % nblk                  # worker-local block (same π for every w!)
-    x = x_ref[...]                # (1, B)
-    B = x.shape[-1]
+def _permk_workers_kernel(seed_ref, x_ref, vals_ref, off_ref, *, n: int):
+    w, j = pl.program_id(0), pl.program_id(1)
+    R, B = x_ref.shape
     chunk = vals_ref.shape[-1]    # B // n
+    x = x_ref[...].astype(jnp.float32)
     seed = seed_ref[0].astype(jnp.uint32)
-    # shared per-block affine permutation: counters (2b, 2b+1)
-    a = (murmur_bits(seed, jnp.uint32(2 * b)) | jnp.uint32(1)) & jnp.uint32(B - 1)
-    c = murmur_bits(seed, jnp.uint32(2 * b + 1)) & jnp.uint32(B - 1)
-    t = (
-        jax.lax.broadcasted_iota(jnp.uint32, (1, chunk), 1)
-        + jnp.uint32(w * chunk)
-    )
-    off = ((a * t + c) & jnp.uint32(B - 1)).astype(jnp.int32)
-    iota = jax.lax.broadcasted_iota(jnp.int32, (chunk, B), 1)
-    onehot = (iota == off.reshape(chunk, 1)).astype(x.dtype)
-    vals = jax.lax.dot_general(
-        onehot, x.reshape(B, 1), (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-    vals_ref[...] = (vals.reshape(1, chunk) * n).astype(vals_ref.dtype)
-    off_ref[...] = off
+    # shared per-block affine permutation (same π_b for every worker):
+    # counters (2b, 2b+1) of block row b
+    b2 = tile_rows(j, R, 1) * jnp.uint32(2)
+    mask = jnp.uint32(B - 1)
+    a = (murmur_bits(seed, b2) | jnp.uint32(1)) & mask       # (R, 1)
+    c = murmur_bits(seed, b2 + jnp.uint32(1)) & mask
+    for q in range(-(-chunk // LANES)):
+        t = (lanes(R, LANES) + (w * chunk + q * LANES)).astype(jnp.uint32)
+        off = ((a * t + c) & mask).astype(jnp.int32)
+        vals = lane_gather(x, off) * float(n)
+        width = min(LANES, chunk - q * LANES)
+        sl = slice(q * LANES, q * LANES + width)
+        vals_ref[:, sl] = vals[:, :width].astype(vals_ref.dtype)
+        off_ref[:, sl] = off[:, :width]
 
 
-def permk_seeded_workers(
-    x3d: jax.Array, seed: jax.Array, *, interpret: bool = True
-):
+def permk_seeded_workers(x3d: jax.Array, seed: jax.Array, *, interpret: bool):
     """PermK uplink: (n, nblk, B) + one shared uint32 seed → values/offsets,
     both (n, nblk, B/n). Values carry the ×n Perm-K scale; the n workers'
     offsets partition [0, B) in every block. Requires n | B (powers of two)."""
@@ -69,22 +61,8 @@ def permk_seeded_workers(
     assert B & (B - 1) == 0, "block width must be a power of two"
     assert B % n == 0, "worker count must divide the block width"
     chunk = B // n
-    x2d = x3d.reshape(n * nblk, B)
-    vals, offs = pl.pallas_call(
-        functools.partial(_permk_workers_kernel, nblk=nblk, n=n),
-        grid=(n * nblk,),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, B), lambda i: (i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, chunk), lambda i: (i, 0)),
-            pl.BlockSpec((1, chunk), lambda i: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n * nblk, chunk), x3d.dtype),
-            jax.ShapeDtypeStruct((n * nblk, chunk), jnp.int32),
-        ],
-        interpret=interpret,
-    )(seed.reshape(1).astype(jnp.int32), x2d)
-    return vals.reshape(n, nblk, chunk), offs.reshape(n, nblk, chunk)
+    return stack_call(
+        functools.partial(_permk_workers_kernel, n=n),
+        [Smem(seed.reshape(1).astype(jnp.int32)), x3d],
+        [(chunk, x3d.dtype), (chunk, jnp.int32)], interpret=interpret,
+    )

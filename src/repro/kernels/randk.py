@@ -3,16 +3,16 @@
 TPU adaptation (DESIGN.md §3/§5): a GPU RandK uses cuRAND + global gather +
 atomics. Neither maps to the TPU. Instead:
 
-* the flat gradient is reshaped to ``(nblk, B)`` blocks; each grid step owns one
-  ``(1, B)`` VMEM tile (B a multiple of 128 → lane-aligned);
-* *gather* and *scatter* are expressed as one-hot matmuls against an iota —
-  a (kb, B) comparison matrix contracted on the MXU, which is the idiomatic
-  TPU way to move irregular indices through a systolic array;
-* the index sampler runs on the host side of the jit (indices are K ≪ d values,
-  so their HBM traffic is negligible), keeping the kernel deterministic and
-  exactly testable against ref.py. A seeded in-kernel sampler using
-  ``pltpu.prng_random_bits`` is provided for the production path
-  (``randk_seeded``) and validated statistically.
+* the flat gradient is reshaped to ``(nblk, B)`` blocks; each grid step owns
+  R whole rows, an ``(R, B)`` VMEM tile (kernels/tiling.py);
+* *gather* is a lane gather inside 128-lane vregs, one vreg-wide slice of the
+  row at a time — it copies values, so it is exact;
+* *scatter-accumulate* is a compare-and-select sweep per sampled coordinate,
+  adding the payloads worker-major in sampling order (the order of XLA's
+  scatter-add in the oracle);
+* the index sampler is a counter-based hash evaluated in-kernel
+  (``randk_seeded_workers``), bit-exactly reproducible by ref.py; a gather
+  with host-supplied offsets (``randk_gather``) serves the ops.py wrappers.
 """
 
 from __future__ import annotations
@@ -21,8 +21,17 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
+
+from .tiling import LANES, Smem, lane_gather, lanes, row_call, stack_call, tile_rows
+
+
+def _rounded_scale(scale: float, dtype) -> float:
+    """The scale as the oracle applies it: rounded to the payload dtype
+    (``values * jnp.asarray(scale, dtype)``); the f32 product of two values of
+    that dtype is exact, so one final rounding matches the oracle."""
+    return float(np.asarray(scale, np.float32).astype(dtype))
 
 
 # ---------------------------------------------------------------------------
@@ -31,37 +40,31 @@ from jax.experimental.pallas import tpu as pltpu
 
 
 def _randk_gather_kernel(x_ref, off_ref, out_ref, *, scale: float):
-    x = x_ref[...]            # (1, B)
-    off = off_ref[...]        # (1, kb)
-    B = x.shape[-1]
-    kb = off.shape[-1]
-    # one-hot (kb, B) gather matrix; contraction runs on the MXU
-    iota = jax.lax.broadcasted_iota(jnp.int32, (kb, B), 1)
-    onehot = (iota == off.reshape(kb, 1)).astype(x.dtype)
-    vals = jax.lax.dot_general(
-        onehot, x.reshape(B, 1), (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )  # (kb, 1)
-    out_ref[...] = (vals.reshape(1, kb) * scale).astype(out_ref.dtype)
+    x = x_ref[...].astype(jnp.float32)        # (R, B)
+    for q in range(off_ref.shape[-1] // LANES):
+        sl = slice(q * LANES, (q + 1) * LANES)
+        vals = lane_gather(x, off_ref[:, sl]) * scale
+        out_ref[:, sl] = vals.astype(out_ref.dtype)
 
 
 def randk_gather(
-    x2d: jax.Array, offsets: jax.Array, scale: float, *, interpret: bool = True
+    x2d: jax.Array, offsets: jax.Array, scale: float, *, interpret: bool
 ) -> jax.Array:
     """x2d (nblk, B), offsets (nblk, kb) → (nblk, kb) scaled values."""
-    nblk, B = x2d.shape
-    _, kb = offsets.shape
-    return pl.pallas_call(
-        functools.partial(_randk_gather_kernel, scale=float(scale)),
-        grid=(nblk,),
-        in_specs=[
-            pl.BlockSpec((1, B), lambda i: (i, 0)),
-            pl.BlockSpec((1, kb), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, kb), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((nblk, kb), x2d.dtype),
-        interpret=interpret,
-    )(x2d, offsets)
+    nrows, kb = offsets.shape
+    L = -(-kb // LANES) * LANES
+    # whole 8-row sublane tiles for the lane gather (the mesh transport
+    # gathers from leaves of any row count)
+    pad = -nrows % 8
+    x2d = jnp.pad(x2d, ((0, pad), (0, 0)))
+    offs = jnp.pad(offsets.astype(jnp.int32), ((0, pad), (0, L - kb)))
+    (out,) = row_call(
+        functools.partial(
+            _randk_gather_kernel, scale=_rounded_scale(scale, x2d.dtype)
+        ),
+        [x2d, offs], [(L, x2d.dtype)], interpret=interpret,
+    )
+    return out[:nrows, :kb]
 
 
 # ---------------------------------------------------------------------------
@@ -69,43 +72,37 @@ def randk_gather(
 # ---------------------------------------------------------------------------
 
 
-def _scatter_accum_kernel(vals_ref, off_ref, out_ref, *, n: int):
-    vals = vals_ref[...]      # (n, 1, kb)
-    offs = off_ref[...]       # (n, 1, kb)
-    kb = vals.shape[-1]
-    B = out_ref.shape[-1]
+def scatter_rows(vals_ref, off_ref, R: int, B: int) -> jax.Array:
+    """Σ over workers of each worker's scatter-add payload into an (R, B)
+    f32 tile: vals/off refs (n, R, kb). Worker-major, then sample order."""
+    n, _, kb = vals_ref.shape
+    lane = lanes(R, B)
 
     def body(w, acc):
-        off_w = jax.lax.dynamic_index_in_dim(offs, w, 0, keepdims=False)  # (1, kb)
-        val_w = jax.lax.dynamic_index_in_dim(vals, w, 0, keepdims=False)  # (1, kb)
-        iota = jax.lax.broadcasted_iota(jnp.int32, (kb, B), 1)
-        onehot = (iota == off_w.reshape(kb, 1)).astype(jnp.float32)
-        # (1, kb) @ (kb, B) scatter-as-matmul; duplicates accumulate.
-        return acc + jax.lax.dot_general(
-            val_w.astype(jnp.float32), onehot, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        vals = vals_ref[w].astype(jnp.float32)  # (R, kb)
+        offs = off_ref[w]
+        for t in range(kb):
+            acc = acc + jnp.where(lane == offs[:, t:t + 1], vals[:, t:t + 1], 0.0)
+        return acc
 
-    acc = jax.lax.fori_loop(0, n, body, jnp.zeros((1, B), jnp.float32))
-    out_ref[...] = (acc / n).astype(out_ref.dtype)
+    return jax.lax.fori_loop(0, n, body, jnp.zeros((R, B), jnp.float32))
+
+
+def _scatter_accum_kernel(vals_ref, off_ref, out_ref):
+    R, B = out_ref.shape
+    n = vals_ref.shape[0]
+    out_ref[...] = (scatter_rows(vals_ref, off_ref, R, B) / n).astype(out_ref.dtype)
 
 
 def scatter_accum(
-    values: jax.Array, offsets: jax.Array, block: int, *, interpret: bool = True
+    values: jax.Array, offsets: jax.Array, block: int, *, interpret: bool
 ) -> jax.Array:
     """values/offsets (n, nblk, kb) → dense (nblk, block) mean over workers."""
-    n, nblk, kb = values.shape
-    return pl.pallas_call(
-        functools.partial(_scatter_accum_kernel, n=n),
-        grid=(nblk,),
-        in_specs=[
-            pl.BlockSpec((n, 1, kb), lambda i: (0, i, 0)),
-            pl.BlockSpec((n, 1, kb), lambda i: (0, i, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, block), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((nblk, block), values.dtype),
-        interpret=interpret,
-    )(values, offsets)
+    (out,) = row_call(
+        _scatter_accum_kernel, [values, offsets.astype(jnp.int32)],
+        [(block, values.dtype)], interpret=interpret,
+    )
+    return out
 
 
 # ---------------------------------------------------------------------------
@@ -131,109 +128,52 @@ def murmur_bits(seed: jax.Array, ctr: jax.Array) -> jax.Array:
 
 
 def _randk_seeded_kernel(seed_ref, x_ref, vals_ref, off_ref, *, scale: float):
-    i = pl.program_id(0)
-    x = x_ref[...]            # (1, B)
-    B = x.shape[-1]
+    w, j = pl.program_id(0), pl.program_id(1)
+    R, B = x_ref.shape
     kb = vals_ref.shape[-1]
-    ctr = jax.lax.broadcasted_iota(jnp.uint32, (1, kb), 1) + jnp.uint32(i * kb)
-    bits = murmur_bits(seed_ref[0].astype(jnp.uint32), ctr)
-    # B is a power of two in production layouts; mask instead of mod.
-    off = (bits & jnp.uint32(B - 1)).astype(jnp.int32)
-    iota = jax.lax.broadcasted_iota(jnp.int32, (kb, B), 1)
-    onehot = (iota == off.reshape(kb, 1)).astype(x.dtype)
-    vals = jax.lax.dot_general(
-        onehot, x.reshape(B, 1), (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-    vals_ref[...] = (vals.reshape(1, kb) * scale).astype(vals_ref.dtype)
-    off_ref[...] = off
-
-
-def randk_seeded(
-    x2d: jax.Array, seed: jax.Array, kb: int, scale: float, *, interpret: bool = True
-):
-    """Production path: sample kb indices per block on-chip (with replacement —
-    unbiased with ω = B/kb, see DESIGN.md §5), gather, scale. Returns
-    (values, offsets), both (nblk, kb). B must be a power of two."""
-    nblk, B = x2d.shape
-    assert B & (B - 1) == 0, "block width must be a power of two"
-    return pl.pallas_call(
-        functools.partial(_randk_seeded_kernel, scale=float(scale)),
-        grid=(nblk,),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, B), lambda i: (i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, kb), lambda i: (i, 0)),
-            pl.BlockSpec((1, kb), lambda i: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((nblk, kb), x2d.dtype),
-            jax.ShapeDtypeStruct((nblk, kb), jnp.int32),
-        ],
-        interpret=interpret,
-    )(seed.reshape(1).astype(jnp.int32), x2d)
-
-
-# ---------------------------------------------------------------------------
-# Worker-batched seeded sampler: the flat engine's uplink kernel
-# ---------------------------------------------------------------------------
-
-
-def _randk_seeded_workers_kernel(
-    seed_ref, x_ref, vals_ref, off_ref, *, scale: float, nblk: int
-):
-    i = pl.program_id(0)          # global block id over n·nblk
-    w = i // nblk                 # worker
-    b = i % nblk                  # worker-local block
-    x = x_ref[...]                # (1, B)
-    B = x.shape[-1]
-    kb = vals_ref.shape[-1]
+    x = x_ref[...].astype(jnp.float32)
+    seed = seed_ref[w].astype(jnp.uint32)
     # worker-local counter stream: block b covers counters [b·kb, (b+1)·kb) —
     # the same stream tree_compress produces per worker, so the flat path is
-    # bit-identical to the per-leaf path on block-aligned layouts.
-    ctr = jax.lax.broadcasted_iota(jnp.uint32, (1, kb), 1) + jnp.uint32(b * kb)
-    bits = murmur_bits(seed_ref[w].astype(jnp.uint32), ctr)
-    off = (bits & jnp.uint32(B - 1)).astype(jnp.int32)
-    iota = jax.lax.broadcasted_iota(jnp.int32, (kb, B), 1)
-    onehot = (iota == off.reshape(kb, 1)).astype(x.dtype)
-    vals = jax.lax.dot_general(
-        onehot, x.reshape(B, 1), (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-    vals_ref[...] = (vals.reshape(1, kb) * scale).astype(vals_ref.dtype)
-    off_ref[...] = off
+    # bit-identical to the per-leaf path on block-aligned layouts. Lanes past
+    # kb hash junk counters and are never stored.
+    rows = tile_rows(j, R, LANES) * jnp.uint32(kb)
+    for q in range(-(-kb // LANES)):
+        ctr = lanes(R, LANES).astype(jnp.uint32) + jnp.uint32(q * LANES) + rows
+        # B is a power of two; mask instead of mod.
+        off = (murmur_bits(seed, ctr) & jnp.uint32(B - 1)).astype(jnp.int32)
+        vals = lane_gather(x, off) * scale
+        width = min(LANES, kb - q * LANES)
+        sl = slice(q * LANES, q * LANES + width)
+        vals_ref[:, sl] = vals[:, :width].astype(vals_ref.dtype)
+        off_ref[:, sl] = off[:, :width]
 
 
 def randk_seeded_workers(
     x3d: jax.Array, seeds: jax.Array, kb: int, scale: float, *,
-    interpret: bool = True,
+    interpret: bool,
 ):
     """Per-worker seeded RandK: (n, nblk, B) + seeds (n,) → values/offsets
-    (n, nblk, kb). Workers are folded into the grid (n·nblk steps) with
-    per-worker seeds read from SMEM; each worker restarts its counter stream
-    at 0, matching the tree path's per-worker key split (DESIGN.md §4.2)."""
-    n, nblk, B = x3d.shape
+    (n, nblk, kb), sampled with replacement (unbiased with ω = B/kb,
+    DESIGN.md §5). The grid is (worker, row tile) with per-worker seeds read
+    from SMEM; each worker restarts its counter stream at 0, matching the
+    tree path's per-worker key split (DESIGN.md §4.2). B is a power of two."""
+    B = x3d.shape[-1]
     assert B & (B - 1) == 0, "block width must be a power of two"
-    x2d = x3d.reshape(n * nblk, B)
-    vals, offs = pl.pallas_call(
+    return stack_call(
         functools.partial(
-            _randk_seeded_workers_kernel, scale=float(scale), nblk=nblk
+            _randk_seeded_kernel, scale=_rounded_scale(scale, x3d.dtype)
         ),
-        grid=(n * nblk,),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, B), lambda i: (i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, kb), lambda i: (i, 0)),
-            pl.BlockSpec((1, kb), lambda i: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n * nblk, kb), x3d.dtype),
-            jax.ShapeDtypeStruct((n * nblk, kb), jnp.int32),
-        ],
-        interpret=interpret,
-    )(seeds.astype(jnp.int32), x2d)
-    return vals.reshape(n, nblk, kb), offs.reshape(n, nblk, kb)
+        [Smem(seeds.astype(jnp.int32)), x3d],
+        [(kb, x3d.dtype), (kb, jnp.int32)], interpret=interpret,
+    )
+
+
+def randk_seeded(
+    x2d: jax.Array, seed: jax.Array, kb: int, scale: float, *, interpret: bool
+):
+    """One worker's seeded RandK: (nblk, B) → values/offsets (nblk, kb)."""
+    vals, offs = randk_seeded_workers(
+        x2d[None], seed.reshape(1), kb, scale, interpret=interpret
+    )
+    return vals[0], offs[0]

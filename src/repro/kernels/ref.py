@@ -319,14 +319,14 @@ def natural_dequant_mean_ref(codes: jax.Array, scales: jax.Array) -> jax.Array:
 
 
 def nibble_pack_ref(q2d: jax.Array) -> jax.Array:
-    """(nblk, B) int8 levels in [-8, 7] → (nblk, B/8) uint32 lane words.
+    """(…, nblk, B) int8 levels in [-8, 7] → (…, nblk, B/8) uint32 lane words.
 
     Level t of each 8-group occupies bits [4t, 4t+4) as a two's-complement
     nibble; this IS the 4-bit wire representation (half a byte per
     coordinate). Requires B % 8 == 0 (lane-aligned layouts always satisfy)."""
-    nblk, B = q2d.shape
+    *lead, B = q2d.shape
     assert B % 8 == 0, "block width must pack into whole uint32 words"
-    nib = (q2d.astype(jnp.int32) & 0xF).astype(jnp.uint32).reshape(nblk, B // 8, 8)
+    nib = (q2d.astype(jnp.int32) & 0xF).astype(jnp.uint32).reshape(*lead, B // 8, 8)
     word = nib[..., 0]
     for t in range(1, 8):
         word = word | (nib[..., t] << jnp.uint32(4 * t))
@@ -334,16 +334,16 @@ def nibble_pack_ref(q2d: jax.Array) -> jax.Array:
 
 
 def nibble_unpack_ref(words: jax.Array, block: int) -> jax.Array:
-    """(nblk, B/8) uint32 lane words → (nblk, B) int8 (sign-extended nibbles).
-    Exact inverse of :func:`nibble_pack_ref` on levels in [-8, 7]."""
-    nblk, nw = words.shape
+    """(…, nblk, B/8) uint32 lane words → (…, nblk, B) int8 (sign-extended
+    nibbles). Exact inverse of :func:`nibble_pack_ref` on levels in [-8, 7]."""
+    *lead, nw = words.shape
     assert nw * 8 == block
     nib = jnp.stack(
         [(words >> jnp.uint32(4 * t)) & jnp.uint32(0xF) for t in range(8)],
         axis=-1,
     ).astype(jnp.int8)                                         # values 0..15
     q = jnp.where(nib >= 8, nib - jnp.int8(16), nib)
-    return q.reshape(nblk, block)
+    return q.reshape(*lead, block)
 
 
 # ---------------------------------------------------------------------------

@@ -229,7 +229,7 @@ def build_train_steps(
     if flat_sync is None:
         flat_sync = replicate_params or not inner
     blk_axes = inner if (
-        inner and lay.nblk % int(np.prod([mesh.shape[a] for a in inner])) == 0
+        inner and lay.rows % int(np.prod([mesh.shape[a] for a in inner])) == 0
     ) else None
     buf_shard = NamedSharding(
         mesh,

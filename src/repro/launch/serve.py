@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import PUBLIC_TO_MODULE, get_arch
 from repro.core.paging import PagedLayout
 from repro.launch.scheduler import ContinuousEngine, ContinuousScheduler, Request
@@ -284,6 +285,7 @@ def main():
         help="pool size override (default: worst-case fit for --slots)",
     )
     args = ap.parse_args()
+    enable_compile_cache()
 
     arch = get_arch(args.arch)
     cfg = reduce_cfg(arch.model, layers=2, d_model=128) if args.reduced else arch.model

@@ -181,13 +181,24 @@ class Topology:
 # ---------------------------------------------------------------------------
 
 
+def make_mesh(shape: tuple, axes: tuple):
+    """The one mesh constructor. Every axis is ``Auto``: the round assembly
+    pins layouts with ``with_sharding_constraint`` and leaves the rest to
+    the partitioner, which ``Explicit`` axes (``jax.make_mesh``'s default)
+    refuse."""
+    import jax
+    from jax.sharding import AxisType
+
+    return jax.make_mesh(
+        tuple(shape), tuple(axes), axis_types=(AxisType.Auto,) * len(axes)
+    )
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     """16×16 single-pod (256 chips) or 2×16×16 two-pod (512 chips) mesh."""
-    import jax
-
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def production_topology(*, multi_pod: bool = False) -> Topology:
@@ -208,9 +219,7 @@ def production_topology(*, multi_pod: bool = False) -> Topology:
 def make_test_mesh(data: int = 2, model: int = 2):
     """Small mesh for CPU sharding tests (requires ≥ data·model host
     devices)."""
-    import jax
-
-    return jax.make_mesh((data, model), ("data", "model"))
+    return make_mesh((data, model), ("data", "model"))
 
 
 def make_federated_mesh(clients: int, model: int = 1):
@@ -218,9 +227,7 @@ def make_federated_mesh(clients: int, model: int = 1):
     client fleet, the model axis carries within-client parallelism (1 for
     cross-device clients). Requires ≥ clients·model host devices — pair
     with XLA_FLAGS=--xla_force_host_platform_device_count for CPU tests."""
-    import jax
-
-    return jax.make_mesh((clients, model), ("data", "model"))
+    return make_mesh((clients, model), ("data", "model"))
 
 
 def worker_axis_names(multi_pod: bool, worker_axes: str) -> tuple:
@@ -303,10 +310,7 @@ def initialize_multiprocess(
     tier) instead of failing at dispatch."""
     import jax
 
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except Exception:
-        pass  # non-CPU backends / older configs: the default is fine
+    jax.config.update("jax_cpu_collectives_implementation", "gloo")
     jax.distributed.initialize(
         coordinator_address=coordinator_address,
         num_processes=num_processes,
@@ -347,6 +351,9 @@ def _launch_procs(
     :func:`run_resilient_cluster`."""
     port = _free_port()
     env_base = dict(os.environ)
+    # CPU simulations by design (gloo collectives): the children never
+    # compete with a parent for an accelerator
+    env_base["JAX_PLATFORMS"] = "cpu"
     env_base["XLA_FLAGS"] = (
         f"--xla_force_host_platform_device_count={devices_per_process} "
         + env_base.get("XLA_FLAGS", "")
@@ -638,7 +645,7 @@ from repro.launch import topology as topo
 pid, nproc = topo.init_from_env()
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
-mesh = jax.make_mesh((jax.device_count(),), ("data",))
+mesh = topo.make_mesh((jax.device_count(),), ("data",))
 t = topo.detect_topology(mesh)
 sh = NamedSharding(mesh, P("data"))
 x = jax.make_array_from_callback(

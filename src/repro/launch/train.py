@@ -20,6 +20,7 @@ import argparse
 import jax
 import jax.numpy as jnp
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import PUBLIC_TO_MODULE, get_arch
 from repro.models import init_params, param_count, reduced as reduce_cfg
 from repro.train import TrainConfig, Trainer
@@ -43,6 +44,7 @@ def main():
     ap.add_argument("--d-model", type=int, default=128)
     ap.add_argument("--ckpt-dir", default=None)
     args = ap.parse_args()
+    enable_compile_cache()
 
     arch = get_arch(args.arch)
     cfg = (

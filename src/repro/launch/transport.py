@@ -253,6 +253,41 @@ class Transport:
         destination layout)."""
         return NamedSharding(self.mesh, P())
 
+    def _row_spec(self, rows: int) -> P:
+        """Spec of an (rows, …) payload stack: worker-sharded when the rows
+        split evenly over the worker axes, else replicated."""
+        size = int(np.prod([self.mesh.shape[a] for a in self.waxes]))
+        if not self.waxes or rows % size:
+            return P()
+        return P(self.waxes if len(self.waxes) != 1 else self.waxes[0])
+
+    def _on_devices(self, fn, in_specs, out_specs):
+        """``fn`` calls the backend-switched block kernels. GSPMD cannot
+        partition a Mosaic kernel, so on a multi-device mesh a Pallas
+        backend runs ``fn`` per device under ``shard_map`` (every mesh
+        axis manual) on the shards ``in_specs`` name; the jnp oracles
+        partition like any other op."""
+        if self.mesh.size == 1 or flat_engine.resolve_backend(self.backend) == "ref":
+            return fn
+        return jax.shard_map(
+            fn, mesh=self.mesh, in_specs=in_specs, out_specs=out_specs,
+            check_vma=False,
+        )
+
+    def _gather(self, x3d, idx3d, scale: float, spec: P):
+        """Per-row gather of an (n, R, L) payload stack laid out by spec."""
+        return self._on_devices(
+            lambda x, i: _gather_along_last(x, i, scale, self.backend),
+            (spec, spec), spec,
+        )(x3d, idx3d)
+
+    def _scatter_mean(self, vals3d, idx3d, L: int):
+        """Replicated scatter-accumulate mean of (n, R, kb) payloads."""
+        return self._on_devices(
+            lambda v, i: _scatter_mean_last(v, i, L, self.backend),
+            (P(), P()), P(),
+        )(vals3d, idx3d)
+
     # -- sync exchange ------------------------------------------------------
 
     def sync_mean(self, grads: PyTree) -> PyTree:
@@ -358,7 +393,6 @@ class Transport:
             self.book("up", kind, bits * up_frac)
         waxes = self.waxes if rows_sharded else ()
         staged = self.staged_payload if rows_sharded else False
-        backend = self.backend
         packed = self.packed_payload
 
         leaves, treedef = jax.tree.flatten(diffs)
@@ -384,7 +418,7 @@ class Transport:
                 C = L // n
                 perm = jax.random.permutation(lk, L)  # shared across workers
                 idx = jnp.broadcast_to(perm.reshape(n, 1, C), (n, R, C))
-                vals = _gather_along_last(x, idx, float(n), backend)
+                vals = self._gather(x, idx, float(n), self._row_spec(n) if staged else P())
                 if staged:
                     vals = jax.lax.with_sharding_constraint(
                         vals, worker_sharded
@@ -443,8 +477,9 @@ class Transport:
                 dense = (acc / n).astype(leaf.dtype)
             elif self.shared_mask:
                 idx = jax.random.randint(lk, (R, kb), 0, L, jnp.int32)
-                vals = _gather_along_last(
-                    x, jnp.broadcast_to(idx, (n, R, kb)), scale, backend
+                vals = self._gather(
+                    x, jnp.broadcast_to(idx, (n, R, kb)), scale,
+                    self._row_spec(n) if staged else P(),
                 )
                 if staged:
                     # pin the gather to the worker-sharded layout so the
@@ -455,12 +490,14 @@ class Transport:
                 # ζ-sized psum over the worker axis; stays sharded on R
                 book_up("psum", _arr_bits(vals) / self.n)
                 vals_mean = jnp.mean(vals, axis=0)                # (R, kb)
-                dense = _scatter_mean_last(
-                    vals_mean[None], idx[None], L, backend
+                dense = self._scatter_mean(
+                    vals_mean[None], idx[None], L
                 ).astype(leaf.dtype)
             else:
                 idx = jax.random.randint(lk, (n, R, kb), 0, L, jnp.int32)
-                vals = _gather_along_last(x, idx, scale, backend)
+                vals = self._gather(
+                    x, idx, scale, self._row_spec(n) if staged else P()
+                )
                 if staged:
                     # stage 1: gather under the worker-sharded layout
                     # (local); stage 2 (below): all-gather only the K-sized
@@ -488,9 +525,7 @@ class Transport:
                     book_up("all-gather", _arr_bits(vals, idx) / self.n)
                     vals = jax.lax.with_sharding_constraint(vals, repl)
                     idx = jax.lax.with_sharding_constraint(idx, repl)
-                dense = _scatter_mean_last(
-                    vals, idx, L, backend
-                ).astype(leaf.dtype)
+                dense = self._scatter_mean(vals, idx, L).astype(leaf.dtype)
 
             out = dense.reshape(shape)
             if osh is not None and staged:
@@ -559,12 +594,15 @@ class Transport:
                 dense = q.astype(jnp.float32) * (norm / s)
             else:  # independent Block-RandK masks
                 idx = jax.random.randint(lk, (n, R, kb), 0, L, jnp.int32)
-                vals = _gather_along_last(x, idx, scale, self.backend)
+                spec = self._row_spec(n)
+                vals = self._gather(x, idx, scale, spec)
                 book_up("all-gather", _arr_bits(vals, idx) / self.n)
-                dense = jax.vmap(
-                    lambda v, i: _scatter_mean_last(
+                # each worker's row decodes where it lives
+                dense = self._on_devices(
+                    jax.vmap(lambda v, i: _scatter_mean_last(
                         v[None], i[None], L, self.backend
-                    )
+                    )),
+                    (spec, spec), spec,
                 )(vals, idx)
             rows.append(dense.reshape((n,) + tuple(shape)))
         return jax.tree.unflatten(treedef, rows)

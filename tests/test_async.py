@@ -198,10 +198,10 @@ def test_deadline_bits_scale_with_arrivals(data):
 def test_transport_uplink_books_only_uploaded_rows():
     """`Transport.uplink_mean(uploaded_rows=u)` scales every up booking by
     u/n while the collective still carries n (zero-padded) rows."""
-    from repro.launch.topology import detect_topology
+    from repro.launch.topology import detect_topology, make_mesh
     from repro.launch.transport import make_transport
 
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     topo = detect_topology(mesh)
     diffs = jax.random.normal(jax.random.PRNGKey(0), (4, 256))
 

@@ -47,7 +47,8 @@ RAGGED_TREES = [
 def test_pack_unpack_roundtrip_identity(tree, block):
     layout = make_layout(tree, block=block)
     buf = pack(layout, tree)
-    assert buf.shape == (layout.nblk, block)
+    assert buf.shape == (layout.rows, block)
+    assert layout.nblk == -(-layout.d // block) <= layout.rows
     out = unpack(layout, buf)
     assert jax.tree.structure(out) == jax.tree.structure(tree)
     for a, b in zip(jax.tree.leaves(out), jax.tree.leaves(tree)):
@@ -70,7 +71,7 @@ def test_pack_stacked_worker_axis():
     stacked = jax.tree.map(lambda x: jnp.stack([x, 2 * x, 3 * x]), tree)
     layout = make_layout(tree, block=128)
     bufs = pack_stacked(layout, stacked)
-    assert bufs.shape == (3, layout.nblk, 128)
+    assert bufs.shape == (3, layout.rows, 128)
     np.testing.assert_allclose(np.asarray(bufs[2]), 3 * np.asarray(bufs[0]))
 
 

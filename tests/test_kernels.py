@@ -153,3 +153,68 @@ def test_qsgd_ops_roundtrip_unbiased():
     omega = min(d / s**2, np.sqrt(d) / s)
     rel = float(jnp.linalg.norm(mean - x) / jnp.linalg.norm(x))
     assert rel < 2.0 * np.sqrt(omega / 1000)
+
+
+# ---------------------------------------------------------------------------
+# Row tiles: a buffer whose block count is not a multiple of the row tile
+# ---------------------------------------------------------------------------
+
+TILE_N, TILE_NBLK, TILE_B = 2, 300, 1024
+
+
+def _tile_case(kernel):
+    from repro.kernels import epilogue as epi
+    from repro.kernels import quantize as qz
+    from repro.kernels.permk import permk_seeded_workers
+    from repro.kernels.randk import randk_seeded_workers
+
+    n, nblk, B = TILE_N, TILE_NBLK, TILE_B
+    k = jax.random.PRNGKey(41)
+    x3d = jax.random.normal(k, (n, nblk, B))
+    g, x = x3d[0], x3d[1] * 0.5
+    seeds = jnp.array([5, 1234567], jnp.uint32)
+    levels, norms = ref.qsgd_block_workers_ref(x3d, seeds, 7)
+    vals, offs = ref.randk_seeded_workers_ref(x3d, seeds, 8, B / 8)
+    P = dict(backend="pallas_interpret")
+    return {
+        "randk": (lambda: randk_seeded_workers(x3d, seeds, 8, B / 8,
+                                               interpret=True),
+                  lambda: ref.randk_seeded_workers_ref(x3d, seeds, 8, B / 8)),
+        "permk": (lambda: permk_seeded_workers(x3d, jnp.uint32(77),
+                                               interpret=True),
+                  lambda: ref.permk_seeded_workers_ref(x3d, jnp.uint32(77), n)),
+        "qsgd": (lambda: qz.qsgd_block_workers(x3d, seeds, 7, **P),
+                 lambda: (levels, norms)),
+        "natural": (lambda: qz.natural_block_workers(x3d, seeds, **P),
+                    lambda: ref.natural_block_workers_ref(x3d, seeds)),
+        "nibble": (lambda: (qz.nibble_pack(levels.reshape(-1, B), **P),),
+                   lambda: (ref.nibble_pack_ref(levels.reshape(-1, B)),)),
+        "scatter_epilogue": (
+            lambda: epi.scatter_epilogue(vals, offs, g, x, 0.1, **P),
+            lambda: epi.scatter_epilogue(vals, offs, g, x, 0.1, backend="ref")),
+        "qsgd_epilogue": (
+            lambda: epi.qsgd_epilogue(levels, norms, g, x, 0.1, 7, **P),
+            lambda: epi.qsgd_epilogue(levels, norms, g, x, 0.1, 7,
+                                      backend="ref")),
+    }[kernel]
+
+
+@pytest.mark.parametrize("kernel", ["randk", "permk", "qsgd", "natural",
+                                    "nibble", "scatter_epilogue",
+                                    "qsgd_epilogue"])
+def test_row_tiles_cover_a_partial_last_tile(kernel):
+    """Kernel == oracle on every block row when the row tile does not divide
+    nblk: the last tile runs past the buffer, and its out-of-range rows must
+    neither leak into real rows nor be lost from them."""
+    from repro.kernels.tiling import lane_bytes, row_tile
+
+    R = row_tile(TILE_NBLK, lane_bytes(TILE_B, 4) + 2 * lane_bytes(8, 4))
+    assert TILE_NBLK > R and TILE_NBLK % R, "shape must leave a partial tile"
+    got, want = (f() for f in _tile_case(kernel))
+    for a, b in zip(got, want):
+        if kernel.endswith("epilogue"):
+            # 1-ulp FMA-fusion tolerance (DESIGN.md §4.4)
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=1e-5, atol=1e-6)
+        else:
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

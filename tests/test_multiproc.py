@@ -57,7 +57,7 @@ from repro.models import init_params, reduced
 
 n_dev = jax.device_count()
 assert n_dev == 4, n_dev
-mesh = jax.make_mesh((n_dev, 1), ("data", "model"))
+mesh = topo.make_test_mesh(n_dev, 1)
 
 t = topo.detect_topology(mesh)
 expect = "dcn" if nproc > 1 else "loopback"
@@ -153,7 +153,7 @@ from repro.models import init_params, reduced
 
 n_dev = jax.device_count()
 assert n_dev == 4, n_dev
-mesh = jax.make_mesh((n_dev, 1), ("data", "model"))
+mesh = topo.make_test_mesh(n_dev, 1)
 
 # recovery contract: rounds < resume replay fault-free (the fleet completed
 # them before the crash), rounds >= resume treat the dead clients as a

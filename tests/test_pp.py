@@ -474,6 +474,7 @@ def test_mesh_pp_round_trajectory_equals_core():
     to core PPMarina."""
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
+    env["JAX_PLATFORMS"] = "cpu"  # fake CPU devices by design
     out = subprocess.run(
         [sys.executable, "-c", _PP_MESH_PROG],
         capture_output=True, text=True, env=env, timeout=560,

@@ -69,11 +69,12 @@ _SUBPROCESS_PROG = textwrap.dedent(
 
     from repro.configs import get_arch
     from repro.launch.distributed import build_train_steps
+    from repro.launch.topology import make_test_mesh
     from repro.models import reduced, init_params, lm_loss
     import dataclasses
 
     assert jax.device_count() == 8
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_test_mesh(4, 2)
 
     arch = get_arch("qwen1.5-0.5b")
     arch = dataclasses.replace(arch, model=reduced(arch.model, layers=2, d_model=64))
@@ -248,6 +249,7 @@ _SUBPROCESS_PROG = textwrap.dedent(
 def test_sharded_steps_execute_on_8_devices():
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
+    env["JAX_PLATFORMS"] = "cpu"  # fake CPU devices by design
     out = subprocess.run(
         [sys.executable, "-c", _SUBPROCESS_PROG],
         capture_output=True,

@@ -17,6 +17,7 @@ from repro.launch.topology import (
     Topology,
     cohort_group_size,
     detect_topology,
+    make_test_mesh,
     num_workers,
     production_topology,
     worker_axis_names,
@@ -149,7 +150,7 @@ def test_cohort_group_size():
 
 
 def test_detect_topology_single_process():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_test_mesh(1, 1)
     t = detect_topology(mesh)
     assert t.n_devices == 1 and t.n_processes == 1
     assert t.devices_per_pod is None
