@@ -42,6 +42,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.tracing import stage
+
 PyTree = Any
 
 DEFAULT_BLOCK = 1024  # 8 × 128 VMEM tile width; must be a power of two
@@ -125,18 +127,27 @@ def make_layout(
     )
 
 
+def _write_leaves(layout: FlatLayout, leaves: list, lead: tuple) -> jax.Array:
+    """Leaves with leading axes ``lead`` → ``(*lead, rows, B)``: each leaf
+    written in place into a zero buffer. (A concatenate compiles to the same
+    in-place writes, but the compiler drops their scope on the way; and
+    ``vmap`` would turn each write into a scatter.)"""
+    flat = jnp.zeros((*lead, layout.rows * layout.block), layout.dtype)
+    for s, leaf in zip(layout.slots, leaves):
+        flat = jax.lax.dynamic_update_slice(
+            flat, leaf.reshape(*lead, s.size).astype(layout.dtype),
+            (0,) * len(lead) + (s.offset,),
+        )
+    return flat.reshape(*lead, layout.rows, layout.block)
+
+
+@stage("flat.pack")
 def pack(layout: FlatLayout, tree: PyTree) -> jax.Array:
-    """Pytree → ``(rows, B)`` padded buffer (one concatenate, zero pad)."""
-    leaves = layout.treedef.flatten_up_to(tree)
-    flat = jnp.concatenate(
-        [jnp.ravel(l).astype(layout.dtype) for l in leaves]
-    )
-    pad = layout.rows * layout.block - layout.d
-    if pad:
-        flat = jnp.pad(flat, (0, pad))
-    return flat.reshape(layout.rows, layout.block)
+    """Pytree → ``(rows, B)`` padded buffer (zeros past ``d``)."""
+    return _write_leaves(layout, layout.treedef.flatten_up_to(tree), ())
 
 
+@stage("flat.unpack")
 def unpack(layout: FlatLayout, buf: jax.Array) -> PyTree:
     """Inverse of :func:`pack`; restores leaf shapes and dtypes."""
     flat = buf.reshape(-1)
@@ -147,9 +158,11 @@ def unpack(layout: FlatLayout, buf: jax.Array) -> PyTree:
     return jax.tree.unflatten(layout.treedef, outs)
 
 
+@stage("flat.pack")
 def pack_stacked(layout: FlatLayout, tree: PyTree) -> jax.Array:
     """Worker-stacked pytree (leading axis n) → ``(n, rows, B)``."""
-    return jax.vmap(lambda t: pack(layout, t))(tree)
+    leaves = layout.treedef.flatten_up_to(tree)
+    return _write_leaves(layout, leaves, leaves[0].shape[:1])
 
 
 # ---------------------------------------------------------------------------
@@ -174,6 +187,7 @@ def seeded_offsets(seed: jax.Array, nblk: int, block: int, kb: int) -> jax.Array
     return (bits & jnp.uint32(block - 1)).astype(jnp.int32)
 
 
+@stage("flat.compress")
 def block_compress(
     x2d: jax.Array, seed: jax.Array, kb: int, scale: float, backend: str = "auto"
 ):
@@ -190,6 +204,7 @@ def block_compress(
     )
 
 
+@stage("flat.compress")
 def block_compress_workers(
     x3d: jax.Array, seeds: jax.Array, kb: int, scale: float, backend: str = "auto"
 ):
@@ -208,6 +223,7 @@ def block_compress_workers(
     )
 
 
+@stage("flat.compress")
 def block_gather(
     x2d: jax.Array, offsets: jax.Array, scale: float, backend: str = "auto"
 ) -> jax.Array:
@@ -224,6 +240,7 @@ def block_gather(
     )
 
 
+@stage("flat.compress")
 def block_scatter_mean(
     values: jax.Array, offsets: jax.Array, block: int, backend: str = "auto"
 ) -> jax.Array:
@@ -244,6 +261,7 @@ def block_scatter_mean(
     )
 
 
+@stage("flat.compress")
 def block_permk_workers(x3d: jax.Array, seed: jax.Array, backend: str = "auto"):
     """PermK uplink: (n, nblk, B) + ONE shared seed → values/offsets
     (n, nblk, B/n). The n workers' offsets partition every block (correlated
@@ -261,6 +279,7 @@ def block_permk_workers(x3d: jax.Array, seed: jax.Array, backend: str = "auto"):
     )
 
 
+@stage("flat.compress")
 def permk_concat_mean(
     values: jax.Array, seed: jax.Array, block: int, backend: str = "auto"
 ) -> jax.Array:
@@ -275,6 +294,7 @@ def permk_concat_mean(
     return ref.permk_concat_mean_ref(values, seed, block)
 
 
+@stage("flat.compress")
 def block_qsgd_workers(x3d: jax.Array, seeds: jax.Array, s: int,
                        backend: str = "auto"):
     """Fused blockwise QSGD uplink: (n, nblk, B) + (n,) seeds →
@@ -287,6 +307,7 @@ def block_qsgd_workers(x3d: jax.Array, seeds: jax.Array, s: int,
     )
 
 
+@stage("flat.compress")
 def block_qsgd_dequant_mean(levels: jax.Array, norms: jax.Array, s: int,
                             backend: str = "auto") -> jax.Array:
     """Fused dequantize-and-mean: (n, nblk, B) int8 + (n, nblk) f32 →
@@ -299,6 +320,7 @@ def block_qsgd_dequant_mean(levels: jax.Array, norms: jax.Array, s: int,
     )
 
 
+@stage("flat.compress")
 def block_natural_workers(x3d: jax.Array, seeds: jax.Array,
                           backend: str = "auto"):
     """Fused blockwise natural-compression uplink: (n, nblk, B) + (n,) seeds
@@ -310,6 +332,7 @@ def block_natural_workers(x3d: jax.Array, seeds: jax.Array,
     )
 
 
+@stage("flat.compress")
 def block_natural_dequant_mean(codes: jax.Array, scales: jax.Array,
                                backend: str = "auto") -> jax.Array:
     """Fused decode-and-mean of natural payloads → (nblk, B) f32."""
@@ -320,6 +343,7 @@ def block_natural_dequant_mean(codes: jax.Array, scales: jax.Array,
     )
 
 
+@stage("flat.compress")
 def nibble_roundtrip(levels: jax.Array, block: int,
                      backend: str = "auto") -> jax.Array:
     """Push int8 levels through the genuine 4-bit wire: pack two-per-byte
@@ -422,6 +446,7 @@ class FlatEngine:
                 f"s={self.s} does not fit the int8 wire"
             )
 
+    @stage("flat.compress")
     def worker_seeds(self, key: jax.Array, n: int) -> jax.Array:
         """(n,) uint32 seeds, mirroring the tree path's per-worker key split."""
         seeds = jax.vmap(key_to_seed)(jax.random.split(key, n))
@@ -429,6 +454,7 @@ class FlatEngine:
             seeds = jax.lax.with_sharding_constraint(seeds, self.seed_constraint)
         return seeds
 
+    @stage("flat.compress")
     def _shared_seed(self, key: jax.Array) -> jax.Array:
         """ONE uint32 seed for the correlated (PermK) sampler, with the same
         partitioner pin as :meth:`worker_seeds`."""
@@ -495,6 +521,7 @@ class FlatEngine:
         return block_scatter_mean(vals, offs, self.layout.block, self.backend)
 
     # -- per-worker dense decode (robust GARs — DESIGN.md §4.9) -------------
+    @stage("flat.compress")
     def worker_dense(self, key: jax.Array, bufs: jax.Array, n: int) -> jax.Array:
         """Decode each worker's payload densely: (n, nblk, B) diffs →
         (n, nblk, B) f32 rows Q_i(Δ_i). The robust aggregation rules need the
@@ -551,6 +578,7 @@ class FlatEngine:
         bufs = pack_stacked(self.layout, diffs)
         return unpack(self.layout, self.aggregate(key, bufs, n, aggregator))
 
+    @stage("flat.compress")
     def aggregate(
         self, key: jax.Array, bufs: jax.Array, n: int, aggregator=None
     ) -> jax.Array:
@@ -605,6 +633,7 @@ class FlatEngine:
         return dense
 
     # -- the fused server epilogue (DESIGN.md §4.7) -------------------------
+    @stage("flat.compress")
     def fused_round(
         self,
         key: jax.Array,

@@ -67,6 +67,8 @@ from typing import Any, Callable, NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
+from repro.tracing import stage
+
 from .compressors import (
     Compressor,
     CorrelatedCompressor,
@@ -124,6 +126,19 @@ class MarinaState:
                                 # instead of materializing (n, nblk, B))
 
 
+def _round_keys(key: jax.Array, p: float, parts: int = 2):
+    """The round's randomness from its key: the coin c_k ~ Bernoulli(p)
+    (1 = sync round) from the first of ``parts`` split keys, the other split
+    keys (``k_q``, or ``k_sel, k_q``), and the downlink and fault keys folded
+    from ``key`` without perturbing the split."""
+    with stage("marina.coin"):
+        k_bern, *rest = jax.random.split(key, parts)
+        c_k = jax.random.bernoulli(k_bern, p)
+        return (c_k, rest, jax.random.fold_in(key, _DOWN_FOLD),
+                jax.random.fold_in(key, _FAULT_FOLD))
+
+
+@stage("marina.backprop")
 def _per_worker_grads(grad_fn: GradFn, params: PyTree, batches: PyTree) -> PyTree:
     """∇f_i at params for every worker: vmap over the leading worker axis."""
     return jax.vmap(grad_fn, in_axes=(None, 0))(params, batches)
@@ -349,6 +364,17 @@ def _uplink_faults(faults, key, trees, ids, n):
     return fault_lib.inject(faults, key, trees, ids, n)
 
 
+@stage("marina.diff")
+def _uplink_diff(faults, key, new, old, ids, n, row_scale=None):
+    """The compressed round's uplink difference ``new − old`` per worker
+    (``grads − h`` on carry rounds), rows scaled by ``row_scale`` where
+    given, with the round's payload faults."""
+    diffs = tree_sub(new, old)
+    if row_scale is not None:
+        diffs = _scale_rows(diffs, row_scale)
+    return _uplink_faults(faults, key, diffs, ids, n)
+
+
 def _sync_faults(faults, key, trees, ids, n):
     """Sync-round payload faults: Byzantine attacks apply (liars lie on
     dense rounds too); ``drop`` does not — the sync round is the rendezvous
@@ -366,6 +392,20 @@ def _uplink_bits_scale(faults, n) -> float:
     return 1.0
 
 
+@stage("marina.metrics")
+def _step_metrics(g, c_k, bits_dense, bits_q, down_q, oracle_sync, oracle_q):
+    """The round's :class:`StepMetrics`: the new estimator's norm and the
+    bit and oracle ledgers, dense on sync rounds (``c_k``)."""
+    return StepMetrics(
+        grad_est_norm=tree_norm(g),
+        bits_per_worker=jnp.where(c_k, bits_dense, bits_q),
+        sync_round=c_k.astype(jnp.int32),
+        oracle_calls=jnp.where(c_k, oracle_sync, oracle_q),
+        down_bits=jnp.where(c_k, bits_dense, down_q),
+    )
+
+
+@stage("marina.carry")
 def _carry_refresh(h_old, grads, faults, c_k, n):
     """Next-round carry h: this round's local gradients — except dropped
     rows on compressed rounds, whose upload the server never consumed: their
@@ -435,9 +475,7 @@ class Marina:
     # -- seed-shaped rounds (two backprops on compressed rounds) ------------
     def _step_recompute(self, state: MarinaState, key: jax.Array, batches: PyTree):
         n = jax.tree.leaves(batches)[0].shape[0]
-        k_bern, k_q = jax.random.split(key)
-        c_k = jax.random.bernoulli(k_bern, self.p)
-        k_f = jax.random.fold_in(key, _FAULT_FOLD)
+        c_k, (k_q,), k_down, k_f = _round_keys(key, self.p)
         ids = jnp.arange(n)
 
         x_old = state.params
@@ -451,15 +489,14 @@ class Marina:
         def compressed_branch(_):
             g_new = _per_worker_grads(self.grad_fn, x_new, batches)
             g_prev = _per_worker_grads(self.grad_fn, x_old, batches)
-            diffs = tree_sub(g_new, g_prev)
-            diffs = _uplink_faults(self.faults, k_f, diffs, ids, n)
+            diffs = _uplink_diff(self.faults, k_f, g_new, g_prev, ids, n)
             delta = _compressed_delta(
                 self.compressor, self.engine, k_q, diffs, state.params, n,
                 self.aggregator,
             )
             delta = _down_roundtrip(
                 self.down_compressor, self.down_engine,
-                jax.random.fold_in(key, _DOWN_FOLD), delta, state.params,
+                k_down, delta, state.params,
             )
             return jax.tree.map(jnp.add, state.g, delta)
 
@@ -474,22 +511,13 @@ class Marina:
         down_q = _down_round_bits(
             self.down_compressor, self.down_engine, state.params, d
         )
-        metrics = StepMetrics(
-            grad_est_norm=tree_norm(g_next),
-            bits_per_worker=jnp.where(c_k, bits_dense, bits_q),
-            sync_round=c_k.astype(jnp.int32),
-            oracle_calls=jnp.where(c_k, 1.0, 2.0),
-            down_bits=jnp.where(c_k, bits_dense, down_q),
-        )
+        metrics = _step_metrics(g_next, c_k, bits_dense, bits_q, down_q, 1.0, 2.0)
         return MarinaState(params=x_new, g=g_next, step=state.step + 1), metrics
 
     # -- gradient-carry lookahead rounds (one backprop, fused epilogue) -----
     def _step_carry(self, state: MarinaState, key: jax.Array, batches: PyTree):
         n = jax.tree.leaves(batches)[0].shape[0]
-        k_bern, k_q = jax.random.split(key)
-        c_k = jax.random.bernoulli(k_bern, self.p)
-        k_down = jax.random.fold_in(key, _DOWN_FOLD)
-        k_f = jax.random.fold_in(key, _FAULT_FOLD)
+        c_k, (k_q,), k_down, k_f = _round_keys(key, self.p)
         ids = jnp.arange(n)
         d = tree_dim(state.params)
 
@@ -516,9 +544,7 @@ class Marina:
                 # subtract-and-pack stays in tree form until here so XLA can
                 # fuse it into the sampler's ζ-sized gather (a packed h would
                 # force an (n, nblk, B) materialization every round)
-                diffs = _uplink_faults(
-                    self.faults, k_f, tree_sub(grads, state.h), ids, n
-                )
+                diffs = _uplink_diff(self.faults, k_f, grads, state.h, ids, n)
                 return self.engine.fused_round(
                     k_q, pack_stacked(lay, diffs), n, state.g, x2d, self.gamma,
                     down=self.down_engine, down_key=k_down,
@@ -530,16 +556,13 @@ class Marina:
                 params=unpack(lay, x_new2d), g=g2d, step=state.step + 1,
                 h=h_new,
             )
-            gnorm = tree_norm(g2d)
         else:
             def sync_branch(_):
                 g_up = _sync_faults(self.faults, k_f, grads, ids, n)
                 return _sync_aggregate(None, self.aggregator, g_up)
 
             def compressed_branch(_):
-                diffs = _uplink_faults(
-                    self.faults, k_f, tree_sub(grads, state.h), ids, n
-                )
+                diffs = _uplink_diff(self.faults, k_f, grads, state.h, ids, n)
                 delta = _compressed_delta(
                     self.compressor, None, k_q, diffs, state.params, n,
                     self.aggregator,
@@ -555,7 +578,6 @@ class Marina:
             new_state = MarinaState(
                 params=x_next, g=g_next, step=state.step + 1, h=h_new
             )
-            gnorm = tree_norm(g_next)
 
         bits_dense = jnp.asarray(32.0 * d)
         bits_q = _round_bits(self.compressor, self.engine, state.params, n)
@@ -565,12 +587,8 @@ class Marina:
         down_q = _down_round_bits(
             self.down_compressor, self.down_engine, state.params, d
         )
-        metrics = StepMetrics(
-            grad_est_norm=gnorm,
-            bits_per_worker=jnp.where(c_k, bits_dense, bits_q),
-            sync_round=c_k.astype(jnp.int32),
-            oracle_calls=jnp.asarray(1.0),
-            down_bits=jnp.where(c_k, bits_dense, down_q),
+        metrics = _step_metrics(
+            new_state.g, c_k, bits_dense, bits_q, down_q, 1.0, 1.0
         )
         return new_state, metrics
 
@@ -638,9 +656,7 @@ class VRMarina:
 
     def _step_recompute(self, state, key, full_batches, mb_batches):
         n = jax.tree.leaves(full_batches)[0].shape[0]
-        k_bern, k_q = jax.random.split(key)
-        c_k = jax.random.bernoulli(k_bern, self.p)
-        k_f = jax.random.fold_in(key, _FAULT_FOLD)
+        c_k, (k_q,), k_down, k_f = _round_keys(key, self.p)
         ids = jnp.arange(n)
 
         x_old = state.params
@@ -655,15 +671,14 @@ class VRMarina:
             # Alg. 2 line 8: same minibatch at x^{k+1} and x^k.
             g_new = _per_worker_grads(self.mb_grad_fn, x_new, mb_batches)
             g_prev = _per_worker_grads(self.mb_grad_fn, x_old, mb_batches)
-            diffs = tree_sub(g_new, g_prev)
-            diffs = _uplink_faults(self.faults, k_f, diffs, ids, n)
+            diffs = _uplink_diff(self.faults, k_f, g_new, g_prev, ids, n)
             delta = _compressed_delta(
                 self.compressor, self.engine, k_q, diffs, state.params, n,
                 self.aggregator,
             )
             delta = _down_roundtrip(
                 self.down_compressor, self.down_engine,
-                jax.random.fold_in(key, _DOWN_FOLD), delta, state.params,
+                k_down, delta, state.params,
             )
             return jax.tree.map(jnp.add, state.g, delta)
 
@@ -679,25 +694,15 @@ class VRMarina:
         down_q = _down_round_bits(
             self.down_compressor, self.down_engine, state.params, d
         )
-        metrics = StepMetrics(
-            grad_est_norm=tree_norm(g_next),
-            bits_per_worker=jnp.where(
-                c_k,
-                jnp.asarray(32.0 * d),
-                bits_q,
-            ),
-            sync_round=c_k.astype(jnp.int32),
-            oracle_calls=jnp.where(c_k, float(m_full), 2.0 * b_prime),
-            down_bits=jnp.where(c_k, jnp.asarray(32.0 * d), down_q),
+        metrics = _step_metrics(
+            g_next, c_k, jnp.asarray(32.0 * d), bits_q, down_q,
+            float(m_full), 2.0 * b_prime,
         )
         return MarinaState(params=x_new, g=g_next, step=state.step + 1), metrics
 
     def _step_carry(self, state, key, full_batches, mb_batches):
         n = jax.tree.leaves(full_batches)[0].shape[0]
-        k_bern, k_q = jax.random.split(key)
-        c_k = jax.random.bernoulli(k_bern, self.p)
-        k_down = jax.random.fold_in(key, _DOWN_FOLD)
-        k_f = jax.random.fold_in(key, _FAULT_FOLD)
+        c_k, (k_q,), k_down, k_f = _round_keys(key, self.p)
         ids = jnp.arange(n)
         d = tree_dim(state.params)
 
@@ -723,9 +728,7 @@ class VRMarina:
                 grads = _per_worker_grads(
                     self.mb_grad_fn, state.params, mb_batches
                 )
-                diffs = _uplink_faults(
-                    self.faults, k_f, tree_sub(grads, state.h), ids, n
-                )
+                diffs = _uplink_diff(self.faults, k_f, grads, state.h, ids, n)
                 g2d, x_new2d = self.engine.fused_round(
                     k_q, pack_stacked(lay, diffs), n, state.g, x2d, self.gamma,
                     down=self.down_engine, down_key=k_down,
@@ -740,7 +743,6 @@ class VRMarina:
                 params=unpack(lay, x_new2d), g=g2d, step=state.step + 1,
                 h=_carry_refresh(state.h, h_new, self.faults, c_k, n),
             )
-            gnorm = tree_norm(g2d)
         else:
             def sync_branch(_):
                 grads = _per_worker_grads(
@@ -753,9 +755,7 @@ class VRMarina:
                 grads = _per_worker_grads(
                     self.mb_grad_fn, state.params, mb_batches
                 )
-                diffs = _uplink_faults(
-                    self.faults, k_f, tree_sub(grads, state.h), ids, n
-                )
+                diffs = _uplink_diff(self.faults, k_f, grads, state.h, ids, n)
                 delta = _compressed_delta(
                     self.compressor, None, k_q, diffs, state.params, n,
                     self.aggregator,
@@ -775,7 +775,6 @@ class VRMarina:
                 step=state.step + 1,
                 h=_carry_refresh(state.h, h_new, self.faults, c_k, n),
             )
-            gnorm = tree_norm(g_next)
 
         m_full = jax.tree.leaves(full_batches)[0].shape[1]
         b_prime = jax.tree.leaves(mb_batches)[0].shape[1]
@@ -786,16 +785,9 @@ class VRMarina:
         down_q = _down_round_bits(
             self.down_compressor, self.down_engine, state.params, d
         )
-        metrics = StepMetrics(
-            grad_est_norm=gnorm,
-            bits_per_worker=jnp.where(
-                c_k,
-                jnp.asarray(32.0 * d),
-                bits_q,
-            ),
-            sync_round=c_k.astype(jnp.int32),
-            oracle_calls=jnp.where(c_k, float(m_full), 1.0 * b_prime),
-            down_bits=jnp.where(c_k, jnp.asarray(32.0 * d), down_q),
+        metrics = _step_metrics(
+            new_state.g, c_k, jnp.asarray(32.0 * d), bits_q, down_q,
+            float(m_full), 1.0 * b_prime,
         )
         return new_state, metrics
 
@@ -849,6 +841,7 @@ def _scale_rows(trees: PyTree, row_scale: jax.Array) -> PyTree:
     )
 
 
+@stage("marina.carry")
 def _pp_carry_refresh(h_old, sel, grads_sel, faults, n):
     """PP server carry-table refresh: h.at[sel] ← ∇f_i for the sampled rows —
     except dropped clients, whose row the server never received, so their
@@ -954,9 +947,7 @@ class PPMarina:
     # -- seed-shaped rounds (two backprops per sampled client) --------------
     def _step_recompute(self, state: MarinaState, key: jax.Array, batches: PyTree):
         n = jax.tree.leaves(batches)[0].shape[0]
-        k_bern, k_sel, k_q = jax.random.split(key, 3)
-        c_k = jax.random.bernoulli(k_bern, self.p)
-        k_f = jax.random.fold_in(key, _FAULT_FOLD)
+        c_k, (k_sel, k_q), k_down, k_f = _round_keys(key, self.p, 3)
 
         x_old = state.params
         x_new = tree_axpy(-self.gamma, state.g, x_old)
@@ -974,35 +965,31 @@ class PPMarina:
             sel_batches = jax.tree.map(take, batches)
             g_new = _per_worker_grads(self.grad_fn, x_new, sel_batches)
             g_prev = _per_worker_grads(self.grad_fn, x_old, sel_batches)
-            diffs = tree_sub(g_new, g_prev)
-            ws = self._cohort_diff_scale(sel, n)
-            if ws is not None:
-                diffs = _scale_rows(diffs, ws)
-            diffs = _uplink_faults(self.faults, k_f, diffs, sel, n)
+            diffs = _uplink_diff(
+                self.faults, k_f, g_new, g_prev, sel, n,
+                self._cohort_diff_scale(sel, n),
+            )
             delta = _compressed_delta(
                 self.compressor, self.engine, k_q, diffs, state.params, self.r,
                 self.aggregator,
             )
             delta = _down_roundtrip(
                 self.down_compressor, self.down_engine,
-                jax.random.fold_in(key, _DOWN_FOLD), delta, state.params,
+                k_down, delta, state.params,
             )
             return jax.tree.map(jnp.add, state.g, delta)
 
         g_next = jax.lax.cond(c_k, sync_branch, compressed_branch, None)
         new_state = MarinaState(params=x_new, g=g_next, step=state.step + 1)
         metrics = self._metrics(
-            c_k, tree_norm(g_next), state.params, n, oracle_factor=2.0
+            c_k, g_next, state.params, n, oracle_factor=2.0
         )
         return new_state, metrics
 
     # -- carry rounds: ONE backprop per sampled client vs the server table --
     def _step_carry(self, state: MarinaState, key: jax.Array, batches: PyTree):
         n = jax.tree.leaves(batches)[0].shape[0]
-        k_bern, k_sel, k_q = jax.random.split(key, 3)
-        c_k = jax.random.bernoulli(k_bern, self.p)
-        k_down = jax.random.fold_in(key, _DOWN_FOLD)
-        k_f = jax.random.fold_in(key, _FAULT_FOLD)
+        c_k, (k_sel, k_q), k_down, k_f = _round_keys(key, self.p, 3)
 
         # the cohort is hoisted out of the cond so the ledger can count the
         # uploads that actually happened (dropped sampled clients don't bill)
@@ -1039,11 +1026,10 @@ class PPMarina:
                     self.grad_fn, state.params, sel_batches
                 )
                 h_sel = jax.tree.map(lambda t: t[sel], state.h)
-                diffs = tree_sub(grads_sel, h_sel)
-                ws = self._cohort_diff_scale(sel, n)
-                if ws is not None:
-                    diffs = _scale_rows(diffs, ws)
-                diffs = _uplink_faults(self.faults, k_f, diffs, sel, n)
+                diffs = _uplink_diff(
+                    self.faults, k_f, grads_sel, h_sel, sel, n,
+                    self._cohort_diff_scale(sel, n),
+                )
                 # the table keeps the RAW client gradients (weights apply at
                 # aggregation): refresh only the sampled rows — minus drops.
                 h_new = _pp_carry_refresh(
@@ -1063,7 +1049,6 @@ class PPMarina:
                 params=unpack(lay, x_new2d), g=g2d, step=state.step + 1,
                 h=h_new,
             )
-            gnorm = tree_norm(g2d)
         else:
             def sync_branch(_):
                 grads = _per_worker_grads(self.grad_fn, state.params, batches)
@@ -1079,11 +1064,10 @@ class PPMarina:
                     self.grad_fn, state.params, sel_batches
                 )
                 h_sel = jax.tree.map(lambda t: t[sel], state.h)
-                diffs = tree_sub(grads_sel, h_sel)
-                ws = self._cohort_diff_scale(sel, n)
-                if ws is not None:
-                    diffs = _scale_rows(diffs, ws)
-                diffs = _uplink_faults(self.faults, k_f, diffs, sel, n)
+                diffs = _uplink_diff(
+                    self.faults, k_f, grads_sel, h_sel, sel, n,
+                    self._cohort_diff_scale(sel, n),
+                )
                 h_new = _pp_carry_refresh(
                     state.h, sel, grads_sel, self.faults, n
                 )
@@ -1104,17 +1088,19 @@ class PPMarina:
             new_state = MarinaState(
                 params=x_next, g=g_next, step=state.step + 1, h=h_new
             )
-            gnorm = tree_norm(g_next)
 
         metrics = self._metrics(
-            c_k, gnorm, state.params, n, oracle_factor=1.0, uploaded=uploaded
+            c_k, new_state.g, state.params, n, oracle_factor=1.0,
+            uploaded=uploaded,
         )
         return new_state, metrics
 
-    def _metrics(self, c_k, gnorm, like, n, oracle_factor, uploaded=None):
-        """Fleet-total uplink from the wire helpers, divided by n: sync
-        rounds cost n·32d, compressed rounds exactly r·ζ_Q (wire.py) — or
-        uploaded·ζ_Q when dropped cohort members never delivered theirs."""
+    @stage("marina.metrics")
+    def _metrics(self, c_k, g, like, n, oracle_factor, uploaded=None):
+        """The estimator's norm, and the fleet-total uplink from the wire
+        helpers, divided by n: sync rounds cost n·32d, compressed rounds
+        exactly r·ζ_Q (wire.py) — or uploaded·ζ_Q when dropped cohort members
+        never delivered theirs."""
         from . import wire
 
         d = tree_dim(like)
@@ -1130,7 +1116,7 @@ class PPMarina:
             self.down_compressor, self.down_engine, like, d
         )
         return StepMetrics(
-            grad_est_norm=gnorm,
+            grad_est_norm=tree_norm(g),
             bits_per_worker=bits_total / n,
             sync_round=c_k.astype(jnp.int32),
             oracle_calls=jnp.where(c_k, 1.0, oracle_factor * self.r / n),

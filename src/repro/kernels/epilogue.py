@@ -38,6 +38,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.tracing import stage
+
 from . import ref as _ref
 from .quantize import natural_rows, qsgd_rows
 from .randk import scatter_rows
@@ -67,12 +69,12 @@ def _finish(g_new, x_ref, gout_ref, xout_ref, gamma):
     xout_ref[...] = _apply(g_new, x_ref[...], gamma).astype(xout_ref.dtype)
 
 
-def _call(kernel, args, x2d, backend):
-    """Row-tiled epilogue call: the last operand is x; outputs (g' f32,
-    x' in x's dtype) of x's shape."""
+def _call(kernel, args, x2d, backend, name):
+    """Row-tiled epilogue call ``name``: the last operand is x; outputs
+    (g' f32, x' in x's dtype) of x's shape."""
     B = x2d.shape[-1]
     return row_call(
-        kernel, [*args, x2d], [(B, jnp.float32), (B, x2d.dtype)],
+        kernel, [*args, x2d], [(B, jnp.float32), (B, x2d.dtype)], name=name,
         interpret=(backend == "pallas_interpret"),
     )
 
@@ -82,6 +84,7 @@ def _delta_epilogue_kernel(d_ref, g_ref, x_ref, gout_ref, xout_ref, *, gamma):
     _finish(g_new, x_ref, gout_ref, xout_ref, gamma)
 
 
+@stage("flat.epilogue")
 def delta_epilogue(delta2d, g2d, x2d, gamma: float, *, backend: str = "auto"):
     """(nblk, B) dense δ + g + x → (g' f32, x' x.dtype) in one sweep."""
     backend = _resolve(backend)
@@ -89,7 +92,7 @@ def delta_epilogue(delta2d, g2d, x2d, gamma: float, *, backend: str = "auto"):
         return _ref.delta_epilogue_ref(delta2d, g2d, x2d, float(gamma))
     return _call(
         functools.partial(_delta_epilogue_kernel, gamma=float(gamma)),
-        [delta2d, g2d], x2d, backend,
+        [delta2d, g2d], x2d, backend, "delta_epilogue",
     )
 
 
@@ -103,6 +106,7 @@ def _mean_epilogue_kernel(gb_ref, x_ref, gout_ref, xout_ref, *, gamma):
     _finish(acc / n, x_ref, gout_ref, xout_ref, gamma)
 
 
+@stage("flat.epilogue")
 def mean_epilogue(gbufs, x2d, gamma: float, *, backend: str = "auto"):
     """Sync-round epilogue: (n, nblk, B) packed worker gradients + x →
     (g' = worker mean f32, x' x.dtype). The worker mean runs over the ONE
@@ -112,7 +116,7 @@ def mean_epilogue(gbufs, x2d, gamma: float, *, backend: str = "auto"):
         return _ref.mean_epilogue_ref(gbufs, x2d, float(gamma))
     return _call(
         functools.partial(_mean_epilogue_kernel, gamma=float(gamma)),
-        [gbufs], x2d, backend,
+        [gbufs], x2d, backend, "mean_epilogue",
     )
 
 
@@ -152,6 +156,7 @@ def _trimmed_delta_kernel(
     _finish(g_new, x_ref, gout_ref, xout_ref, gamma)
 
 
+@stage("flat.epilogue")
 def trimmed_delta_epilogue(bufs, g2d, x2d, gamma: float, lo: int, hi: int, *,
                            backend: str = "auto"):
     """Robust compressed-round epilogue: per-worker dense payload rows
@@ -166,7 +171,7 @@ def trimmed_delta_epilogue(bufs, g2d, x2d, gamma: float, lo: int, hi: int, *,
         functools.partial(
             _trimmed_delta_kernel, lo=int(lo), hi=int(hi), gamma=float(gamma),
         ),
-        [bufs, g2d], x2d, backend,
+        [bufs, g2d], x2d, backend, "trimmed_delta_epilogue",
     )
 
 
@@ -174,6 +179,7 @@ def _trimmed_sync_kernel(b_ref, x_ref, gout_ref, xout_ref, *, lo, hi, gamma):
     _finish(_trimmed_rows(b_ref, lo, hi), x_ref, gout_ref, xout_ref, gamma)
 
 
+@stage("flat.epilogue")
 def trimmed_sync_epilogue(bufs, x2d, gamma: float, lo: int, hi: int, *,
                           backend: str = "auto"):
     """Robust sync-round epilogue: (n, nblk, B) packed worker gradients + x →
@@ -186,7 +192,7 @@ def trimmed_sync_epilogue(bufs, x2d, gamma: float, lo: int, hi: int, *,
         functools.partial(
             _trimmed_sync_kernel, lo=int(lo), hi=int(hi), gamma=float(gamma),
         ),
-        [bufs], x2d, backend,
+        [bufs], x2d, backend, "trimmed_sync_epilogue",
     )
 
 
@@ -205,6 +211,7 @@ def _scatter_epilogue_kernel(
             xout_ref, gamma)
 
 
+@stage("flat.epilogue")
 def scatter_epilogue(values, offsets, g2d, x2d, gamma: float, *,
                      backend: str = "auto"):
     """Seeded-RandK epilogue: payloads (n, nblk, kb) ×2 + g + x → (g', x').
@@ -217,7 +224,7 @@ def scatter_epilogue(values, offsets, g2d, x2d, gamma: float, *,
     return _call(
         functools.partial(_scatter_epilogue_kernel, gamma=float(gamma)),
         [values.astype(jnp.float32), offsets.astype(jnp.int32), g2d], x2d,
-        backend,
+        backend, "scatter_epilogue",
     )
 
 
@@ -229,6 +236,7 @@ def _qsgd_epilogue_kernel(
             gout_ref, xout_ref, gamma)
 
 
+@stage("flat.epilogue")
 def qsgd_epilogue(levels, norms, g2d, x2d, gamma: float, s: int, *,
                   backend: str = "auto"):
     """Packed block-QSGD epilogue: (n, nblk, B) int8 levels + (n, nblk) f32
@@ -241,7 +249,7 @@ def qsgd_epilogue(levels, norms, g2d, x2d, gamma: float, s: int, *,
                                       s)
     return _call(
         functools.partial(_qsgd_epilogue_kernel, s=int(s), gamma=float(gamma)),
-        [levels, per_block(norms), g2d], x2d, backend,
+        [levels, per_block(norms), g2d], x2d, backend, "qsgd_epilogue",
     )
 
 
@@ -253,6 +261,7 @@ def _natural_epilogue_kernel(
             gout_ref, xout_ref, gamma)
 
 
+@stage("flat.epilogue")
 def natural_epilogue(codes, scales, g2d, x2d, gamma: float, *,
                      backend: str = "auto"):
     """Natural-compression epilogue: (n, nblk, B) int8 codes + (n, nblk) f32
@@ -263,5 +272,5 @@ def natural_epilogue(codes, scales, g2d, x2d, gamma: float, *,
                                          float(gamma))
     return _call(
         functools.partial(_natural_epilogue_kernel, gamma=float(gamma)),
-        [codes, per_block(scales), g2d], x2d, backend,
+        [codes, per_block(scales), g2d], x2d, backend, "natural_epilogue",
     )

@@ -118,6 +118,7 @@ def paged_attn_decode(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, H, hd), vpages.dtype),
         interpret=(backend == "pallas_interpret"),
+        name="paged_attn_decode",
     )(tables.astype(jnp.int32), n_valid.astype(jnp.int32), q, kpages, vpages)
 
 

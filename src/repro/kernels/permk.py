@@ -64,5 +64,6 @@ def permk_seeded_workers(x3d: jax.Array, seed: jax.Array, *, interpret: bool):
     return stack_call(
         functools.partial(_permk_workers_kernel, n=n),
         [Smem(seed.reshape(1).astype(jnp.int32)), x3d],
-        [(chunk, x3d.dtype), (chunk, jnp.int32)], interpret=interpret,
+        [(chunk, x3d.dtype), (chunk, jnp.int32)],
+        name="permk_seeded_workers", interpret=interpret,
     )

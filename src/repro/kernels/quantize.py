@@ -73,7 +73,7 @@ def block_sumsq(x2d: jax.Array, *, backend: str = "auto") -> jax.Array:
         return _ref.block_sumsq_ref(x2d)
     nblk, B = x2d.shape
     (out,) = row_call(
-        _block_sumsq_kernel, [x2d], [(1, jnp.float32)],
+        _block_sumsq_kernel, [x2d], [(1, jnp.float32)], name="block_sumsq",
         interpret=(backend == "pallas_interpret"),
     )
     return out.reshape(nblk)
@@ -100,7 +100,7 @@ def qsgd_quantize(
     (out,) = row_call(
         functools.partial(_qsgd_kernel, s=int(s)),
         [x2d, u2d, Whole(norm.reshape(1, 1).astype(jnp.float32))],
-        [(B, jnp.int8)], interpret=(backend == "pallas_interpret"),
+        [(B, jnp.int8)], name="qsgd_quantize", interpret=(backend == "pallas_interpret"),
     )
     return out
 
@@ -120,7 +120,7 @@ def qsgd_dequantize(
     (out,) = row_call(
         functools.partial(_dequant_kernel, s=int(s)),
         [q2d, Whole(norm.reshape(1, 1).astype(jnp.float32))],
-        [(B, jnp.float32)], interpret=(backend == "pallas_interpret"),
+        [(B, jnp.float32)], name="qsgd_dequantize", interpret=(backend == "pallas_interpret"),
     )
     return out
 
@@ -163,7 +163,7 @@ def qsgd_block_workers(
     q, norms = stack_call(
         functools.partial(_qsgd_block_workers_kernel, s=int(s)),
         [Smem(seeds.astype(jnp.int32)), x3d],
-        [(B, jnp.int8), (1, jnp.float32)],
+        [(B, jnp.int8), (1, jnp.float32)], name="qsgd_block_workers",
         interpret=(backend == "pallas_interpret"),
     )
     return q, norms.reshape(n, nblk)
@@ -196,7 +196,7 @@ def qsgd_dequant_mean(
     B = levels.shape[-1]
     (out,) = row_call(
         functools.partial(_qsgd_dequant_mean_kernel, s=int(s)),
-        [levels, per_block(norms)], [(B, jnp.float32)],
+        [levels, per_block(norms)], [(B, jnp.float32)], name="qsgd_dequant_mean",
         interpret=(backend == "pallas_interpret"),
     )
     return out
@@ -236,7 +236,7 @@ def natural_block_workers(
     n, nblk, B = x3d.shape
     codes, scales = stack_call(
         _natural_block_workers_kernel, [Smem(seeds.astype(jnp.int32)), x3d],
-        [(B, jnp.int8), (1, jnp.float32)],
+        [(B, jnp.int8), (1, jnp.float32)], name="natural_block_workers",
         interpret=(backend == "pallas_interpret"),
     )
     return codes, scales.reshape(n, nblk)
@@ -269,7 +269,7 @@ def natural_dequant_mean(
     B = codes.shape[-1]
     (out,) = row_call(
         _natural_dequant_mean_kernel, [codes, per_block(scales)],
-        [(B, jnp.float32)], interpret=(backend == "pallas_interpret"),
+        [(B, jnp.float32)], name="natural_dequant_mean", interpret=(backend == "pallas_interpret"),
     )
     return out
 
@@ -331,7 +331,7 @@ def nibble_pack(q: jax.Array, *, backend: str = "auto") -> jax.Array:
     return _stacked(
         lambda q3: stack_call(
             _nibble_pack_kernel, [q3, Whole(_pack_matrix(B))],
-            [(B // 8, jnp.uint32)], interpret=(backend == "pallas_interpret"),
+            [(B // 8, jnp.uint32)], name="nibble_pack", interpret=(backend == "pallas_interpret"),
         )[0],
         q,
     )
@@ -360,7 +360,7 @@ def nibble_unpack(
     return _stacked(
         lambda w3: stack_call(
             _nibble_unpack_kernel, [w3, Whole(_unpack_matrix(block))],
-            [(block, jnp.int8)], interpret=(backend == "pallas_interpret"),
+            [(block, jnp.int8)], name="nibble_unpack", interpret=(backend == "pallas_interpret"),
         )[0],
         words,
     )
@@ -392,7 +392,7 @@ def absmax_quant_rows(x2d: jax.Array, *, backend: str = "auto"):
     R, W = x2d.shape
     codes, scales = row_call(
         _absmax_quant_rows_kernel, [x2d],
-        [(W, jnp.int8), (1, jnp.float32)],
+        [(W, jnp.int8), (1, jnp.float32)], name="absmax_quant_rows",
         interpret=(backend == "pallas_interpret"),
     )
     return codes, scales.reshape(R)
@@ -414,6 +414,6 @@ def absmax_dequant_rows(
     (out,) = row_call(
         _absmax_dequant_rows_kernel,
         [codes, scales.reshape(R, 1).astype(jnp.float32)],
-        [(W, jnp.float32)], interpret=(backend == "pallas_interpret"),
+        [(W, jnp.float32)], name="absmax_dequant_rows", interpret=(backend == "pallas_interpret"),
     )
     return out

@@ -62,7 +62,7 @@ def randk_gather(
         functools.partial(
             _randk_gather_kernel, scale=_rounded_scale(scale, x2d.dtype)
         ),
-        [x2d, offs], [(L, x2d.dtype)], interpret=interpret,
+        [x2d, offs], [(L, x2d.dtype)], name="randk_gather", interpret=interpret,
     )
     return out[:nrows, :kb]
 
@@ -100,7 +100,7 @@ def scatter_accum(
     """values/offsets (n, nblk, kb) → dense (nblk, block) mean over workers."""
     (out,) = row_call(
         _scatter_accum_kernel, [values, offsets.astype(jnp.int32)],
-        [(block, values.dtype)], interpret=interpret,
+        [(block, values.dtype)], name="scatter_accum", interpret=interpret,
     )
     return out
 
@@ -165,7 +165,8 @@ def randk_seeded_workers(
             _randk_seeded_kernel, scale=_rounded_scale(scale, x3d.dtype)
         ),
         [Smem(seeds.astype(jnp.int32)), x3d],
-        [(kb, x3d.dtype), (kb, jnp.int32)], interpret=interpret,
+        [(kb, x3d.dtype), (kb, jnp.int32)], name="randk_seeded",
+        interpret=interpret,
     )
 
 

@@ -80,8 +80,8 @@ class Whole:
         self.array = array
 
 
-def row_call(kernel, args, outs, *, interpret: bool):
-    """pallas_call over row tiles of the block axis.
+def row_call(kernel, args, outs, *, name: str, interpret: bool):
+    """pallas_call ``name`` over row tiles of the block axis.
 
     ``args`` are 2-D ``(nblk, w)`` operands, tiled (R, w); 3-D worker stacks
     ``(n, nblk, w)``, tiled (n, R, w) — all workers in every step; or
@@ -110,6 +110,7 @@ def row_call(kernel, args, outs, *, interpret: bool):
         out_specs=[pl.BlockSpec((R, w), lambda i: (i, 0)) for w, _ in outs],
         out_shape=[jax.ShapeDtypeStruct((nblk, w), dt) for w, dt in outs],
         interpret=interpret,
+        name=name,
     )(*arrays)
 
 
@@ -121,8 +122,9 @@ class Smem:
         self.array = array
 
 
-def stack_call(kernel, args, outs, *, interpret: bool):
-    """pallas_call over (worker, row tile) of ``(n, nblk, w)`` worker stacks.
+def stack_call(kernel, args, outs, *, name: str, interpret: bool):
+    """pallas_call ``name`` over (worker, row tile) of ``(n, nblk, w)``
+    worker stacks.
 
     Each stack operand is tiled (R, w) of one worker — the grid never folds
     workers into the row axis, so no (n, nblk) ↔ (n·nblk) relayout is ever
@@ -152,6 +154,7 @@ def stack_call(kernel, args, outs, *, interpret: bool):
         out_specs=[pl.BlockSpec((None, R, w), row) for w, _ in outs],
         out_shape=[jax.ShapeDtypeStruct((n, nblk, w), dt) for w, dt in outs],
         interpret=interpret,
+        name=name,
     )(*[a.array if isinstance(a, (Whole, Smem)) else a for a in args])
 
 

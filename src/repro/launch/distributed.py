@@ -44,8 +44,9 @@ from repro.core import flat as flat_engine
 from repro.core.marina import (
     _FAULT_FOLD,
     _carry_refresh,
+    _per_worker_grads,
     _sync_faults,
-    _uplink_faults,
+    _uplink_diff,
 )
 from repro.models import init_params, lm_loss
 from repro.launch import sharding as shd
@@ -210,7 +211,7 @@ def build_train_steps(
     grad_one = jax.grad(loss_fn)
 
     def worker_grads(params, batch):
-        return jax.vmap(grad_one, in_axes=(None, 0))(params, batch)
+        return _per_worker_grads(grad_one, params, batch)
 
     # sync rounds ride the flat buffer: one fused mean over the packed
     # (n, nblk, B) buffer — a single worker-axis psum of d — instead of one
@@ -315,9 +316,8 @@ def build_train_steps(
         def compressed_step(params, g, h, batch, key):
             x_new = descend(params, g)
             g_plus = worker_grads(x_new, batch)
-            diffs = jax.tree.map(jnp.subtract, g_plus, h)
-            diffs = _uplink_faults(
-                faults, jax.random.fold_in(key, _FAULT_FOLD), diffs,
+            diffs = _uplink_diff(
+                faults, jax.random.fold_in(key, _FAULT_FOLD), g_plus, h,
                 jnp.arange(n), n,
             )
             g_new = jax.tree.map(jnp.add, g, compressed_delta(key, diffs))
@@ -347,9 +347,8 @@ def build_train_steps(
             x_new = descend(params, g)
             g_plus = worker_grads(x_new, batch)
             g_minus = worker_grads(params, batch)
-            diffs = jax.tree.map(jnp.subtract, g_plus, g_minus)
-            diffs = _uplink_faults(
-                faults, jax.random.fold_in(key, _FAULT_FOLD), diffs,
+            diffs = _uplink_diff(
+                faults, jax.random.fold_in(key, _FAULT_FOLD), g_plus, g_minus,
                 jnp.arange(n), n,
             )
             g_new = jax.tree.map(jnp.add, g, compressed_delta(key, diffs))

@@ -18,7 +18,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import flat as flat_engine
-from repro.core.marina import _FAULT_FOLD, _pp_carry_refresh, _uplink_faults
+from repro.core.marina import _FAULT_FOLD, _pp_carry_refresh, _uplink_diff
 from repro.launch import sharding as shd
 from repro.launch.topology import cohort_group_size
 
@@ -192,9 +192,8 @@ def build_pp_steps(
             x_new = descend(params, g)
             cg = cohort_grads(x_new, batch, sel)
             h_sel = jax.tree.map(lambda t: t[sel], h)
-            diffs = jax.tree.map(jnp.subtract, cg, h_sel)
-            diffs = _uplink_faults(
-                faults, jax.random.fold_in(key, _FAULT_FOLD), diffs,
+            diffs = _uplink_diff(
+                faults, jax.random.fold_in(key, _FAULT_FOLD), cg, h_sel,
                 sel, n,
             )
             g_new = jax.tree.map(jnp.add, g, pp_delta(key, diffs))
@@ -217,9 +216,8 @@ def build_pp_steps(
             x_new = descend(params, g)
             g_plus = cohort_grads(x_new, batch, sel)
             g_minus = cohort_grads(params, batch, sel)
-            diffs = jax.tree.map(jnp.subtract, g_plus, g_minus)
-            diffs = _uplink_faults(
-                faults, jax.random.fold_in(key, _FAULT_FOLD), diffs,
+            diffs = _uplink_diff(
+                faults, jax.random.fold_in(key, _FAULT_FOLD), g_plus, g_minus,
                 sel, n,
             )
             g_new = jax.tree.map(jnp.add, g, pp_delta(key, diffs))

@@ -303,7 +303,7 @@ class Transport:
         self.book("down", "broadcast", wire.downlink_dense_bits(d))
         if self.flat_sync:
             lay = self.sync_layout
-            bufs = jax.vmap(lambda t: flat_engine.pack(lay, t))(grads)
+            bufs = flat_engine.pack_stacked(lay, grads)
             bufs = jax.lax.with_sharding_constraint(bufs, self.sync_buf_shard)
             g_new = flat_engine.unpack(lay, jnp.mean(bufs, axis=0))
             return jax.tree.map(

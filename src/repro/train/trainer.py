@@ -24,7 +24,6 @@ instead of every step.
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Any, Callable, Optional
 
 import jax
@@ -56,6 +55,7 @@ from repro.core import (
 from repro.data import HeterogeneousLMData, make_prefix_embeddings, worker_batches
 from repro.models import lm_loss
 from repro.models.config import ModelConfig
+from repro.tracing import op_stages, span, stage
 
 PyTree = Any
 
@@ -126,10 +126,10 @@ class TrainMetrics:
     bits_cum: list = dataclasses.field(default_factory=list)
     down_cum: list = dataclasses.field(default_factory=list)
     oracle_cum: list = dataclasses.field(default_factory=list)
-    wall: list = dataclasses.field(default_factory=list)
     skipped_cum: list = dataclasses.field(default_factory=list)
 
 
+@stage("trainer.guard")
 def _state_finite(state: PyTree) -> jax.Array:
     """Scalar bool: every floating leaf of the optimizer state (params,
     estimator g, carried h, …) is all-finite. The non-finite round guard's
@@ -326,6 +326,7 @@ class Trainer:
         self._jitted_chunk = jax.jit(self._chunk, donate_argnums=(0,))
 
     # ------------------------------------------------------------------
+    @stage("trainer.data")
     def _batches(self, step: int, per_worker: int):
         toks = worker_batches(self.data, step, per_worker)
         batch = {"tokens": toks}
@@ -364,7 +365,8 @@ class Trainer:
 
         def body(c, step):
             state, bits, down, oracle, skipped = c
-            key = jax.random.fold_in(base_key, step)
+            with stage("trainer.data"):
+                key = jax.random.fold_in(base_key, step)
             full_b = self._batches(step, self.tcfg.batch_per_worker)
             mb_b = self._batches(10**7 + step, self.tcfg.mb_per_worker)
             new_state, met = self._step(state, key, full_b, mb_b)
@@ -373,13 +375,15 @@ class Trainer:
                 # revert the ENTIRE state on a bad round — a finite-looking
                 # h/g paired with reverted params would desynchronize the
                 # estimator recursion.
-                new_state = jax.tree.map(
-                    lambda new, old: jnp.where(ok, new, old), new_state, state
-                )
-                met = met._replace(
-                    grad_est_norm=jnp.where(ok, met.grad_est_norm, 0.0)
-                )
-                skipped = skipped + jnp.where(ok, 0.0, 1.0)
+                with stage("trainer.guard"):
+                    new_state = jax.tree.map(
+                        lambda new, old: jnp.where(ok, new, old), new_state,
+                        state,
+                    )
+                    met = met._replace(
+                        grad_est_norm=jnp.where(ok, met.grad_est_norm, 0.0)
+                    )
+                    skipped = skipped + jnp.where(ok, 0.0, 1.0)
             return (
                 new_state,
                 bits + met.bits_per_worker,
@@ -391,6 +395,15 @@ class Trainer:
         carry, mets = jax.lax.scan(body, carry, steps)
         last_met = jax.tree.map(lambda a: a[-1], mets)
         return carry, last_met
+
+    def chunk_stages(self, carry, steps) -> dict:
+        """``{instruction: stage}`` of the chunk compiled for these
+        arguments (:func:`repro.tracing.op_stages`): names every device
+        operation of a traced chunk by its stage. Where the chunk already ran
+        with the persistent compilation cache on, this is a cache load."""
+        return op_stages(
+            self._jitted_chunk.lower(carry, steps).compile().as_text()
+        )
 
     def eval_loss(self, params, step: int = 10**6) -> float:
         b = self._batches(step, self.tcfg.batch_per_worker)
@@ -436,56 +449,56 @@ class Trainer:
         if tc.ckpt_dir:
             s = latest_step(tc.ckpt_dir)
             if s is not None:
-                # the communication/oracle ledgers resume WITH the state
-                # (which includes the carried h_i^k in carry mode): a restart
-                # that zeroes them silently shifts every resumed loss-vs-bits
-                # curve (the Fig. 1/2 x-axis) left. A corrupt file raises
-                # CheckpointCorruptionError from load_checkpoint — NOT caught
-                # by the KeyError format tiers below.
-                like = {
-                    "state": state,
-                    "bits": np.zeros((), np.float32),
-                    "down": np.zeros((), np.float32),
-                    "oracle": np.zeros((), np.float32),
-                    "skipped": np.zeros((), np.float32),
-                }
-                try:
-                    ck = load_checkpoint(tc.ckpt_dir, s, like)
-                    state = ck["state"]
-                    bits = float(ck["bits"])
-                    down = float(ck["down"])
-                    oracle = float(ck["oracle"])
-                    skipped = float(ck["skipped"])
-                except KeyError:
+                with span("trainer.restore"):
+                    # the communication/oracle ledgers resume WITH the state
+                    # (which includes the carried h_i^k in carry mode): a restart
+                    # that zeroes them silently shifts every resumed loss-vs-bits
+                    # curve (the Fig. 1/2 x-axis) left. A corrupt file raises
+                    # CheckpointCorruptionError from load_checkpoint — NOT caught
+                    # by the KeyError format tiers below.
+                    like = {
+                        "state": state,
+                        "bits": np.zeros((), np.float32),
+                        "down": np.zeros((), np.float32),
+                        "oracle": np.zeros((), np.float32),
+                        "skipped": np.zeros((), np.float32),
+                    }
                     try:
-                        # pre-guard checkpoint: no skipped-rounds ledger.
-                        del like["skipped"]
                         ck = load_checkpoint(tc.ckpt_dir, s, like)
                         state = ck["state"]
                         bits = float(ck["bits"])
                         down = float(ck["down"])
                         oracle = float(ck["oracle"])
+                        skipped = float(ck["skipped"])
                     except KeyError:
                         try:
-                            # pre-downlink checkpoint: bits/oracle only.
-                            del like["down"]
+                            # pre-guard checkpoint: no skipped-rounds ledger.
+                            del like["skipped"]
                             ck = load_checkpoint(tc.ckpt_dir, s, like)
                             state = ck["state"]
                             bits = float(ck["bits"])
+                            down = float(ck["down"])
                             oracle = float(ck["oracle"])
                         except KeyError:
-                            # pre-ledger checkpoint (bare state tree): resume
-                            # the iterates and accept zeroed ledgers rather
-                            # than refuse the directory outright.
-                            state = load_checkpoint(tc.ckpt_dir, s, state)
-                start = s + 1
+                            try:
+                                # pre-downlink checkpoint: bits/oracle only.
+                                del like["down"]
+                                ck = load_checkpoint(tc.ckpt_dir, s, like)
+                                state = ck["state"]
+                                bits = float(ck["bits"])
+                                oracle = float(ck["oracle"])
+                            except KeyError:
+                                # pre-ledger checkpoint (bare state tree): resume
+                                # the iterates and accept zeroed ledgers rather
+                                # than refuse the directory outright.
+                                state = load_checkpoint(tc.ckpt_dir, s, state)
+                    start = s + 1
 
         # the chunk carry is donated; copy so self.params0 (aliased into the
         # initial state) survives for eval or a second run().
         state = jax.tree.map(jnp.array, state)
 
         hist = TrainMetrics()
-        t0 = time.time()
 
         # anchor the loss-vs-bits curve at the pre-training state (step
         # start−1, 0 bits uplinked): the uniform chunking below only logs
@@ -494,14 +507,14 @@ class Trainer:
         from repro.core.tree_util import tree_norm
 
         hist.step.append(start - 1)
-        hist.loss.append(self.eval_loss(state.params, start))
+        with span("trainer.eval"):
+            hist.loss.append(self.eval_loss(state.params, start))
         hist.grad_est_norm.append(
             float(tree_norm(state.g)) if hasattr(state, "g") else 0.0
         )
         hist.bits_cum.append(bits)
         hist.down_cum.append(down)
         hist.oracle_cum.append(oracle)
-        hist.wall.append(time.time() - t0)
         hist.skipped_cum.append(skipped)
 
         prev = start
@@ -509,39 +522,41 @@ class Trainer:
             # one fused device dispatch for steps [prev, bound]; the bits /
             # down-bits / oracle / skipped ledgers accumulate on device, read
             # back once per chunk.
-            steps_arr = jnp.arange(prev, bound + 1, dtype=jnp.int32)
-            # four distinct zero buffers: the chunk carry is donated, and
-            # donating one buffer several times is an XLA error
-            zeros = [jnp.zeros((), jnp.float32) for _ in range(4)]
-            (state, chunk_bits, chunk_down, chunk_oracle, chunk_skip), met = (
-                self._jitted_chunk((state, *zeros), steps_arr)
-            )
-            bits += float(chunk_bits)
-            down += float(chunk_down)
-            oracle += float(chunk_oracle)
-            skipped += float(chunk_skip)
+            with span("trainer.chunk", step_num=bound):
+                steps_arr = jnp.arange(prev, bound + 1, dtype=jnp.int32)
+                # four distinct zero buffers: the chunk carry is donated, and
+                # donating one buffer several times is an XLA error
+                zeros = [jnp.zeros((), jnp.float32) for _ in range(4)]
+                (state, chunk_bits, chunk_down, chunk_oracle, chunk_skip), met = (
+                    self._jitted_chunk((state, *zeros), steps_arr)
+                )
+                bits += float(chunk_bits)
+                down += float(chunk_down)
+                oracle += float(chunk_oracle)
+                skipped += float(chunk_skip)
             prev = bound + 1
 
             if is_log:
-                loss = self.eval_loss(state.params, bound)
+                with span("trainer.eval"):
+                    loss = self.eval_loss(state.params, bound)
                 hist.step.append(bound)
                 hist.loss.append(loss)
                 hist.grad_est_norm.append(float(met.grad_est_norm))
                 hist.bits_cum.append(bits)
                 hist.down_cum.append(down)
                 hist.oracle_cum.append(oracle)
-                hist.wall.append(time.time() - t0)
                 hist.skipped_cum.append(skipped)
             if is_ckpt:
-                save_checkpoint(
-                    tc.ckpt_dir,
-                    bound,
-                    {
-                        "state": state,
-                        "bits": np.float32(bits),
-                        "down": np.float32(down),
-                        "oracle": np.float32(oracle),
-                        "skipped": np.float32(skipped),
-                    },
-                )
+                with span("trainer.checkpoint"):
+                    save_checkpoint(
+                        tc.ckpt_dir,
+                        bound,
+                        {
+                            "state": state,
+                            "bits": np.float32(bits),
+                            "down": np.float32(down),
+                            "oracle": np.float32(oracle),
+                            "skipped": np.float32(skipped),
+                        },
+                    )
         return state, hist

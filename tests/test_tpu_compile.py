@@ -8,6 +8,8 @@ The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and the test workers all import this file.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -15,12 +17,13 @@ from jax.experimental.layout import Format, Layout
 from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_arch
-from repro.core.flat import DEFAULT_BLOCK
+from repro.core.flat import DEFAULT_BLOCK, FlatEngine, make_layout
 from repro.kernels import epilogue as epi
 from repro.kernels import quantize
 from repro.kernels.permk import permk_seeded_workers
 from repro.kernels.randk import randk_gather, randk_seeded_workers, scatter_accum
 from repro.models import init_params
+from repro.tracing import op_stages
 
 B = DEFAULT_BLOCK
 KB = 8
@@ -57,16 +60,27 @@ def nblk():
     return -(-d // B)
 
 
-def _compile(one_chip, fn, *shapes):
-    """Compile ``fn`` on row-major operands and results. Left free, the
-    compiler lays a small leading worker axis out second-minor at the jit
-    boundary and copies it into the kernel's row-major layout — a copy the
-    engine's in-program buffers never pay, which at n = 4 overflows HBM."""
+#: the kernel each wrapper runs, where its name is not the wrapper's
+KERNEL = {"randk_seeded_workers": "randk_seeded"}
+
+
+def _compile(one_chip, fn, *shapes, kernel=None):
+    """Compile ``fn`` on row-major operands and results; returns the
+    compiled text, in which the Pallas kernel ``kernel`` must be an
+    instruction named after it. Left free, the compiler lays a small leading
+    worker axis out second-minor at the jit boundary and copies it into the
+    kernel's row-major layout — a copy the engine's in-program buffers never
+    pay, which at n = 4 overflows HBM."""
     fmt = lambda nd: Format(Layout(major_to_minor=tuple(range(nd))), one_chip)
     args = [jax.ShapeDtypeStruct(s, dt, sharding=fmt(len(s))) for s, dt in shapes]
     outs = jax.tree.map(lambda o: fmt(o.ndim), jax.eval_shape(fn, *args))
     hlo = jax.jit(fn, out_shardings=outs).lower(*args).compile().as_text()
     assert "tpu_custom_call" in hlo
+    if kernel is not None:
+        name = KERNEL.get(kernel, kernel)
+        assert re.search(rf"^\s*(ROOT )?%{name}(\.\d+)? = .* custom-call\(", hlo, re.M), (
+            f"no instruction named {name}")
+    return hlo
 
 
 f32, i32, u32, i8 = jnp.float32, jnp.int32, jnp.uint32, jnp.int8
@@ -145,14 +159,14 @@ SERVER = [name for name, _, _ in _server(2, 1)]
 @pytest.mark.parametrize("kernel", UPLINKS)
 def test_uplink_kernel_compiles_at_qwen_width(one_chip, nblk, kernel, n):
     (fn, shapes), = [(f, s) for k, f, s in _uplinks(n, nblk) if k == kernel]
-    _compile(one_chip, fn, *shapes)
+    _compile(one_chip, fn, *shapes, kernel=kernel)
 
 
 @pytest.mark.parametrize("n", [2, 4])
 @pytest.mark.parametrize("kernel", SERVER)
 def test_server_kernel_compiles_at_qwen_width(one_chip, nblk, kernel, n):
     (fn, shapes), = [(f, s) for k, f, s in _server(n, nblk) if k == kernel]
-    _compile(one_chip, fn, *shapes)
+    _compile(one_chip, fn, *shapes, kernel=kernel)
 
 
 @pytest.mark.parametrize("kernel", ["randk_gather", "block_sumsq",
@@ -174,4 +188,23 @@ def test_flat_vector_kernel_compiles_at_qwen_width(one_chip, nblk, kernel):
             lambda q, nm: quantize.qsgd_dequantize(q, nm, 7, **P),
             [((nblk, B), i8), scalar]),
     }[kernel]
-    _compile(one_chip, fn, *shapes)
+    _compile(one_chip, fn, *shapes, kernel=kernel)
+
+
+def test_fused_qsgd_round_maps_its_kernels_to_their_stages(one_chip, nblk):
+    """The compiled fused QSGD round at qwen's flat width: the uplink and
+    the 4-bit wire run in ``flat.compress``, the epilogue in
+    ``flat.epilogue``, each kernel an instruction named after it."""
+    lay = make_layout(jax.ShapeDtypeStruct((nblk * B,), f32), block=B)
+    eng = FlatEngine(lay, backend="pallas", sampler="qsgd", s=7)
+    buf = ((lay.rows, B), f32)
+    hlo = _compile(
+        one_chip, lambda k, d, g, x: eng.fused_round(k, d, 2, g, x, GAMMA),
+        ((2,), u32), ((2, lay.rows, B), f32), buf, buf,
+    )
+    stages = op_stages(hlo)
+    want = {"qsgd_block_workers": "flat.compress", "nibble_pack": "flat.compress",
+            "nibble_unpack": "flat.compress", "qsgd_epilogue": "flat.epilogue"}
+    for kernel, stage in want.items():
+        got = {st for i, st in stages.items() if re.fullmatch(rf"{kernel}(\.\d+)?", i)}
+        assert got == {stage}, (kernel, got)
