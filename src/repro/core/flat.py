@@ -9,8 +9,11 @@ replaces that with a single packed representation:
 * :class:`FlatLayout` — a *static* description of how a pytree maps onto one
   zero-padded ``(rows, B)`` block buffer (B lane-aligned, default 1024): the
   ``nblk`` blocks that hold the tree, rounded up to whole row tiles.
-  Computed once per parameter structure; pack/unpack are pure reshapes +
-  one concatenate/slice, jit/vmap/donate friendly.
+  Computed once per parameter structure, with its rows split into runs:
+  where every leaf is whole blocks, each is reshaped straight to its block
+  rows; otherwise one flat run writes every leaf into one vector and
+  relayouts it into rows. Pack concatenates the runs along the rows,
+  unpack slices them.
 * :class:`FlatEngine` — the fused compress → uplink → decompress-mean
   pipeline over that buffer. Per-worker payloads are ``(nblk, kb)`` seeded
   RandK values whose indices are *regenerated from the seed* on the server
@@ -81,6 +84,22 @@ class LeafSlot:
 
 
 @dataclasses.dataclass(frozen=True)
+class Run:
+    """Rows ``[row, row + nrows)`` of the buffer, written in one piece.
+
+    An ``aligned`` run is one leaf whose offset and size are whole blocks:
+    it is already ``(size // B, B)`` block rows. A flat run's leaves are
+    flattened, written after one another and cut into rows, zero padded
+    past the last leaf.
+    """
+
+    row: int
+    nrows: int
+    slots: tuple    # indices into FlatLayout.slots
+    aligned: bool
+
+
+@dataclasses.dataclass(frozen=True)
 class FlatLayout:
     """Precomputed static layout of a pytree over a padded block buffer.
 
@@ -88,12 +107,14 @@ class FlatLayout:
     ``slots[i].offset``; every entry past ``d`` is a structural zero
     (DESIGN.md §4.1). ``nblk`` blocks hold the tree and are what the wire
     carries; the buffer has ``rows`` ≥ nblk of them, and the rows past nblk
-    are zeros that compress to zeros and book no wire bits.
+    are zeros that compress to zeros and book no wire bits. Pack and unpack
+    move each of the ``runs`` (:class:`Run`, see ``_runs``) as a whole.
     Hashable/static: safe to close over in jitted functions.
     """
 
     treedef: Any
     slots: tuple
+    runs: tuple     # Run, in row order from row 0 (see _runs)
     d: int          # true dimension Σ leaf sizes
     block: int      # B, lane-aligned power of two
     nblk: int       # number of blocks = ceil(d / B)
@@ -104,6 +125,26 @@ class FlatLayout:
     def padded(self) -> int:
         """Coordinates the wire accounts for: nblk whole blocks."""
         return self.nblk * self.block
+
+    @property
+    def row_share(self) -> float:
+        """Share of the ``d`` coordinates that aligned runs move as block
+        rows, with no flattening or relayout: 1 or 0 (``_runs``)."""
+        moved = sum(self.slots[r.slots[0]].size for r in self.runs if r.aligned)
+        return moved / self.d if self.d else 0.0
+
+
+def _runs(slots: list, block: int, rows: int) -> tuple:
+    """Split the rows into :class:`Run` s: one a leaf where every leaf is
+    whole blocks, else one flat run of every leaf and all ``rows``. The TPU
+    compiler writes a flat run's relayout to a buffer of its own and then
+    copies it into the rows, which costs more than aligned leaves beside it
+    save (timed on the chip up to a quarter of the coordinates aligned,
+    PERF.md §6)."""
+    if all(s.size % block == 0 for s in slots):
+        return tuple(Run(s.offset // block, s.size // block, (i,), True)
+                     for i, s in enumerate(slots))
+    return (Run(0, rows, tuple(range(len(slots))), False),)
 
 
 def make_layout(
@@ -122,23 +163,38 @@ def make_layout(
     nblk = max(1, -(-d // block))
     rows = -(-nblk // ROW_ALIGN) * ROW_ALIGN
     return FlatLayout(
-        treedef=treedef, slots=tuple(slots), d=d, block=block, nblk=nblk,
-        rows=rows, dtype=dtype,
+        treedef=treedef, slots=tuple(slots), runs=_runs(slots, block, rows), d=d,
+        block=block, nblk=nblk, rows=rows, dtype=dtype,
     )
 
 
 def _write_leaves(layout: FlatLayout, leaves: list, lead: tuple) -> jax.Array:
-    """Leaves with leading axes ``lead`` → ``(*lead, rows, B)``: each leaf
-    written in place into a zero buffer. (A concatenate compiles to the same
+    """Leaves with leading axes ``lead`` → ``(*lead, rows, B)``, one piece a
+    run, concatenated along the rows with zeros past the last run. An
+    aligned leaf is reshaped straight to its block rows. The leaves of a
+    flat run are written in place into a zero buffer of the run's rows,
+    which is then cut into rows. (A concatenate compiles to the same
     in-place writes, but the compiler drops their scope on the way; and
     ``vmap`` would turn each write into a scatter.)"""
-    flat = jnp.zeros((*lead, layout.rows * layout.block), layout.dtype)
-    for s, leaf in zip(layout.slots, leaves):
-        flat = jax.lax.dynamic_update_slice(
-            flat, leaf.reshape(*lead, s.size).astype(layout.dtype),
-            (0,) * len(lead) + (s.offset,),
-        )
-    return flat.reshape(*lead, layout.rows, layout.block)
+    B, dt = layout.block, layout.dtype
+    parts = []
+    for run in layout.runs:
+        if run.aligned:
+            parts.append(leaves[run.slots[0]].reshape(*lead, run.nrows, B)
+                         .astype(dt))
+            continue
+        flat = jnp.zeros((*lead, run.nrows * B), dt)
+        for i in run.slots:
+            s = layout.slots[i]
+            flat = jax.lax.dynamic_update_slice(
+                flat, leaves[i].reshape(*lead, s.size).astype(dt),
+                (0,) * len(lead) + (s.offset - run.row * B,),
+            )
+        parts.append(flat.reshape(*lead, run.nrows, B))
+    tail = layout.rows - sum(run.nrows for run in layout.runs)
+    if tail:
+        parts.append(jnp.zeros((*lead, tail, B), dt))
+    return jnp.concatenate(parts, axis=-2)
 
 
 @stage("flat.pack")
@@ -149,12 +205,20 @@ def pack(layout: FlatLayout, tree: PyTree) -> jax.Array:
 
 @stage("flat.unpack")
 def unpack(layout: FlatLayout, buf: jax.Array) -> PyTree:
-    """Inverse of :func:`pack`; restores leaf shapes and dtypes."""
-    flat = buf.reshape(-1)
-    outs = [
-        flat[s.offset : s.offset + s.size].reshape(s.shape).astype(s.dtype)
-        for s in layout.slots
-    ]
+    """Inverse of :func:`pack`; restores leaf shapes and dtypes. An aligned
+    leaf is its rows of ``buf`` reshaped; the leaves of a flat run are cut
+    from the flattened rows of that run."""
+    outs = [None] * len(layout.slots)
+    for run in layout.runs:
+        rows = buf[run.row : run.row + run.nrows]
+        if run.aligned:
+            outs[run.slots[0]] = rows
+            continue
+        flat, base = rows.reshape(-1), run.row * layout.block
+        for i in run.slots:
+            s = layout.slots[i]
+            outs[i] = flat[s.offset - base : s.offset - base + s.size]
+    outs = [o.reshape(s.shape).astype(s.dtype) for o, s in zip(outs, layout.slots)]
     return jax.tree.unflatten(layout.treedef, outs)
 
 

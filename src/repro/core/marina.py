@@ -120,10 +120,9 @@ class MarinaState:
                        # buffer in the fused carry path, tree otherwise)
     step: jax.Array
     h: Optional[PyTree] = None  # carry mode: per-worker ∇f_i(x^k), a
-                                # worker-stacked tree (kept in tree form even
-                                # on the fused path: the subtract-and-pack
-                                # then fuses into the ζ-sized sampler gather
-                                # instead of materializing (n, nblk, B))
+                                # worker-stacked tree, in tree form even on
+                                # the fused path (pack_stacked still
+                                # materializes the (n, rows, B) difference)
 
 
 def _round_keys(key: jax.Array, p: float, parts: int = 2):
@@ -541,9 +540,6 @@ class Marina:
                 )
 
             def compressed_branch(_):
-                # subtract-and-pack stays in tree form until here so XLA can
-                # fuse it into the sampler's ζ-sized gather (a packed h would
-                # force an (n, nblk, B) materialization every round)
                 diffs = _uplink_diff(self.faults, k_f, grads, state.h, ids, n)
                 return self.engine.fused_round(
                     k_q, pack_stacked(lay, diffs), n, state.g, x2d, self.gamma,

@@ -75,6 +75,99 @@ def test_pack_stacked_worker_axis():
     np.testing.assert_allclose(np.asarray(bufs[2]), 3 * np.asarray(bufs[0]))
 
 
+# Leaf shapes in widths of B. Keys sort in flatten order.
+ORACLE_TREES = {
+    # qwen-shaped: every leaf whole blocks, a layer-stacked 3-D leaf and a
+    # leaf whose minor dimension is 2.75·B
+    "aligned": lambda B: {"a_embed": ((6, B), jnp.float32),
+                          "b_norm": ((B,), jnp.float32),
+                          "c_stack": ((2, 3, B), jnp.float32),
+                          "d_gate": ((4, 11 * B // 4), jnp.float32)},
+    "misaligned": lambda B: {"a": ((3, 5), jnp.float32),
+                             "b": ((7,), jnp.float32),
+                             "c": ((B + 3,), jnp.float32)},
+    # musicgen-shaped: an aligned leaf, a 1.5·B norm, then whole-block
+    # sizes left off the block boundary by it: one flat run
+    "mixed": lambda B: {"a_embed": ((4, B), jnp.float32),
+                        "b_norm": ((3 * B // 2,), jnp.float32),
+                        "c_w": ((2, B), jnp.float32),
+                        "d_w": ((3, B), jnp.float32)},
+    # whole blocks but for a last odd-sized leaf: one flat run as well
+    "odd_tail": lambda B: {"a": ((6, B), jnp.float32),
+                           "b": ((B,), jnp.float32),
+                           "c": ((3,), jnp.float32)},
+    "bf16": lambda B: {"a": ((2, B), jnp.bfloat16),
+                       "b": ((5,), jnp.float32),
+                       "c": ((B - 5,), jnp.bfloat16)},
+    "scalar": lambda B: {"a": ((B,), jnp.float32),
+                         "b": ((), jnp.float32),
+                         "c": ((2, B), jnp.float32)},
+}
+
+
+def _oracle_tree(name, B, lead):
+    spec = ORACLE_TREES[name](B)
+    keys = jax.random.split(jax.random.PRNGKey(7), len(spec))
+    return {k: jax.random.normal(key, (*lead, *shape)).astype(dt)
+            for key, (k, (shape, dt)) in zip(keys, sorted(spec.items()))}
+
+
+def _oracle_buffer(layout, leaves):
+    """NumPy: flatten and concatenate in order, zero pad, cut into rows."""
+    flat = np.concatenate([np.asarray(l, np.float32).reshape(-1) for l in leaves])
+    flat = np.pad(flat, (0, layout.rows * layout.block - flat.size))
+    return flat.reshape(layout.rows, layout.block)
+
+
+def _bits(a):
+    a = np.asarray(a)
+    return a.view(np.uint16 if a.dtype.itemsize == 2 else np.uint32)
+
+
+@pytest.mark.parametrize("name", sorted(ORACLE_TREES))
+@pytest.mark.parametrize("block", [128, 1024])
+@pytest.mark.parametrize("lead", [(), (3,)], ids=["pack", "stacked"])
+def test_pack_unpack_match_numpy_oracle(name, block, lead):
+    """``pack`` (lead ()) and ``pack_stacked`` (lead (3,)) give the NumPy
+    concatenate-and-pad buffer bit for bit, whatever the split into
+    aligned and flat runs, and ``unpack`` gives each tree back."""
+    tree = _oracle_tree(name, block, lead)
+    layout = make_layout(_oracle_tree(name, block, ()), block=block)
+    buf = pack(layout, tree) if not lead else pack_stacked(layout, tree)
+    assert buf.shape == (*lead, layout.rows, block) and buf.dtype == jnp.float32
+    for w in range(lead[0] if lead else 1):
+        at = (lambda t: t[w]) if lead else (lambda t: t)
+        want = _oracle_buffer(layout, [at(l) for l in jax.tree.leaves(tree)])
+        np.testing.assert_array_equal(_bits(at(buf)), _bits(want))
+        out = unpack(layout, at(buf))
+        assert jax.tree.structure(out) == jax.tree.structure(tree)
+        for a, b in zip(jax.tree.leaves(out), jax.tree.leaves(tree)):
+            assert a.shape == b.shape[len(lead):] and a.dtype == b.dtype
+            np.testing.assert_array_equal(_bits(a), _bits(at(b)))
+
+
+@pytest.mark.parametrize("name, share, runs", [
+    ("aligned", 1.0, [(0, 6, (0,), True), (6, 1, (1,), True),
+                      (7, 6, (2,), True), (13, 11, (3,), True)]),
+    ("mixed", 0.0, [(0, 32, (0, 1, 2, 3), False)]),
+    ("misaligned", 0.0, [(0, 32, (0, 1, 2), False)]),
+    ("odd_tail", 0.0, [(0, 32, (0, 1, 2), False)]),
+])
+def test_layout_runs_and_row_share(name, share, runs):
+    """Where every leaf is whole blocks, each is a run of its own rows and
+    ``row_share`` is 1; one leaf off a block (the first, a norm after an
+    aligned leaf, or the last) makes one flat run of the whole tree and
+    every row, and the share is 0."""
+    B = 128
+    layout = make_layout(_oracle_tree(name, B, ()), block=B)
+    got = [(r.row, r.nrows, r.slots, r.aligned) for r in layout.runs]
+    assert got == runs
+    assert layout.rows == 32
+    assert sum(r.nrows for r in layout.runs) == (
+        layout.nblk if share else layout.rows)
+    assert layout.row_share == share
+
+
 def test_seeded_offsets_match_kernel_rng():
     """Server-side index regeneration is bit-exact vs the kernel sampler."""
     x2d = jax.random.normal(jax.random.PRNGKey(0), (3, 256))
