@@ -287,11 +287,11 @@ def block_compress_workers(
     )
 
 
-@stage("flat.compress")
 def block_gather(
     x2d: jax.Array, offsets: jax.Array, scale: float, backend: str = "auto"
 ) -> jax.Array:
-    """Gather+scale with host-supplied offsets: (nblk, B), (nblk, kb) → (nblk, kb)."""
+    """Gather+scale with host-supplied offsets: (nblk, B), (nblk, kb) → (nblk, kb).
+    Its caller names the stage (the mesh transport's ``transport.uplink``)."""
     backend = resolve_backend(backend)
     if backend == "ref":
         from repro.kernels import ref
@@ -304,14 +304,15 @@ def block_gather(
     )
 
 
-@stage("flat.compress")
 def block_scatter_mean(
     values: jax.Array, offsets: jax.Array, block: int, backend: str = "auto"
 ) -> jax.Array:
     """Scatter-accumulate mean over workers: (n, nblk, kb) ×2 → (nblk, block).
 
     The only dense buffer is the single (nblk, block) accumulator — the n
-    worker payloads stay ζ-sized (never densified per worker).
+    worker payloads stay ζ-sized (never densified per worker). Its callers
+    name the stage: ``flat.compress`` in the engine, ``transport.uplink`` in
+    the mesh transport.
     """
     backend = resolve_backend(backend)
     if backend == "ref":

@@ -45,6 +45,7 @@ from repro.core.marina import (
     _FAULT_FOLD,
     _carry_refresh,
     _per_worker_grads,
+    _round_keys,
     _sync_faults,
     _uplink_diff,
 )
@@ -53,6 +54,7 @@ from repro.launch import sharding as shd
 from repro.launch.participation import build_pp_steps, pp_cohort_schedule  # noqa: F401
 from repro.launch.topology import detect_topology, num_workers, worker_axis_names
 from repro.launch.transport import make_transport
+from repro.tracing import stage
 
 PyTree = Any
 
@@ -72,6 +74,9 @@ class StepBundle:
     meta: dict = dataclasses.field(default_factory=dict)  # builder decisions
     # (participation mode, cohort-compute vs masked fallback, flat-PP path)
     transport: Any = None  # the Transport whose ledger priced this bundle
+    # where the entries take their arguments: (params, g[, h]) and the batch
+    state_shardings: tuple = ()
+    batch_shardings: Any = None
 
 
 # ---------------------------------------------------------------------------
@@ -262,10 +267,15 @@ def build_train_steps(
     def sync_uplink(grads):
         return _sync_faults(faults, sync_fault_key, grads, jnp.arange(n), n)
 
+    @stage("marina.update")
     def descend(params, g):
         return jax.tree.map(
             lambda w, gg: w - gamma * gg.astype(w.dtype), params, g
         )
+
+    @stage("marina.update")
+    def add_delta(g, delta):
+        return jax.tree.map(jnp.add, g, delta)
 
     def robust_delta(key, diffs, rows_n):
         """Robust compressed-round delta: per-worker dense payload rows →
@@ -320,15 +330,14 @@ def build_train_steps(
                 faults, jax.random.fold_in(key, _FAULT_FOLD), g_plus, h,
                 jnp.arange(n), n,
             )
-            g_new = jax.tree.map(jnp.add, g, compressed_delta(key, diffs))
+            g_new = add_delta(g, compressed_delta(key, diffs))
             # dropped rows keep their old h (the server never heard from
             # them); c_k=False — this IS the compressed branch
             h_new = _carry_refresh(h, g_plus, faults, jnp.asarray(False), n)
             return x_new, g_new, h_new
 
         def train_step(params, g, h, batch, key):
-            k_b, k_q = jax.random.split(key)
-            c_k = jax.random.bernoulli(k_b, p)
+            c_k, (k_q,), _, _ = _round_keys(key, p)
             return jax.lax.cond(
                 c_k,
                 lambda _: sync_step(params, g, h, batch),
@@ -351,12 +360,11 @@ def build_train_steps(
                 faults, jax.random.fold_in(key, _FAULT_FOLD), g_plus, g_minus,
                 jnp.arange(n), n,
             )
-            g_new = jax.tree.map(jnp.add, g, compressed_delta(key, diffs))
+            g_new = add_delta(g, compressed_delta(key, diffs))
             return x_new, g_new
 
         def train_step(params, g, batch, key):
-            k_b, k_q = jax.random.split(key)
-            c_k = jax.random.bernoulli(k_b, p)
+            c_k, (k_q,), _, _ = _round_keys(key, p)
             return jax.lax.cond(
                 c_k,
                 lambda _: sync_step(params, g, batch),
@@ -453,6 +461,8 @@ def build_train_steps(
             **({"faults": faults.attack} if faults is not None else {}),
         },
         transport=transport,
+        state_shardings=state_out,
+        batch_shardings=batch_shard,
     )
 
 

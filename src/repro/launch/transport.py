@@ -57,6 +57,7 @@ from repro.core import flat as flat_engine
 from repro.core import wire
 from repro.kernels import ref as kref
 from repro.launch.topology import Topology
+from repro.tracing import stage
 
 PyTree = Any
 
@@ -290,6 +291,7 @@ class Transport:
 
     # -- sync exchange ------------------------------------------------------
 
+    @stage("transport.sync")
     def sync_mean(self, grads: PyTree) -> PyTree:
         """Dense worker-axis mean of the stacked gradients: one fused psum
         over the packed (nblk, B) flat buffer when ``flat_sync`` (packing
@@ -330,6 +332,7 @@ class Transport:
 
     # -- compressed uplink --------------------------------------------------
 
+    @stage("transport.uplink")
     def uplink_mean(
         self,
         key: jax.Array,

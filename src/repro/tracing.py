@@ -33,6 +33,9 @@ STAGES = {
     "flat.unpack": "buffer -> tree",
     "marina.metrics": "the estimator's norm and the round's bit ledger",
     "trainer.guard": "the non-finite guard and its revert",
+    "transport.uplink": "the mesh's compressed uplink: per-leaf gather, worker all-gather, scatter-mean",
+    "transport.sync": "the mesh's dense sync exchange: the worker psum of the gradients",
+    "marina.update": "the mesh's x -= gamma * g and g += delta (no fused epilogue there)",
 }
 
 #: host span -> what it covers

@@ -4,8 +4,12 @@ from compiled instructions to stages (``repro.tracing``)."""
 from __future__ import annotations
 
 import glob
+import json
 import os
 import re
+import subprocess
+import sys
+import textwrap
 
 import jax
 import jax.numpy as jnp
@@ -60,11 +64,17 @@ def qsgd_chunk():
     return tr, carry, steps, hlo
 
 
+#: the sharded round's exchanges
+EXCHANGES = {"transport.uplink", "transport.sync"}
+#: the stages of the sharded round alone, which the trainer's chunk does not run
+MESH_ONLY = EXCHANGES | {"marina.update"}
+
+
 def test_chunk_stages_name_every_stage_the_round_runs(qsgd_chunk):
     tr, carry, steps, hlo = qsgd_chunk
     stages = tr.chunk_stages(carry, steps)
     assert stages == tracing.op_stages(hlo)
-    assert set(stages.values()) == set(tracing.STAGES)
+    assert set(stages.values()) == set(tracing.STAGES) - MESH_ONLY
 
 
 def test_few_loop_body_ops_are_left_without_a_stage(qsgd_chunk):
@@ -129,3 +139,62 @@ def test_run_writes_host_spans_into_the_profile(tmp_path):
     assert counts["trainer.chunk"] == 3 and counts["trainer.eval"] == 5
     assert counts["trainer.checkpoint"] == 3 and counts["trainer.restore"] == 1
     assert np.isfinite(hist.loss).all()
+
+
+_MESH_STAGES = textwrap.dedent(
+    r"""
+    import os
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    import collections, dataclasses, json, re
+    import jax
+    from repro import tracing
+    from repro.configs import get_arch
+    from repro.models import init_params, reduced
+    from repro.train.mesh import MeshTrainer
+
+    arch = get_arch("qwen1.5-0.5b")
+    cfg = dataclasses.replace(reduced(arch.model, layers=1, d_model=128), d_ff=256)
+    arch = dataclasses.replace(arch, model=cfg)
+    tr = MeshTrainer(arch, batch_per_worker=1, seq_len=32, gamma=0.05,
+                     backend="pallas_interpret")
+    state = tr.init(lambda: init_params(jax.random.PRNGKey(0), cfg))
+    text = tr.compiled_text(state)
+    stages = tr.chunk_stages(state)
+    op = re.compile(r'^\s*(?:ROOT\s+)?%([\w.\-]+)\s*=\s*(?:\([^=]*?\)|\S+)'
+                    r'\s+([\w\-]+)\((.*)$', re.M)
+    seen = collections.Counter()
+    for name, code, rest in op.findall(text):
+        on = re.search(r'op_name="([^"]*)"', rest)
+        on = on.group(1) if on else ""
+        if "randk_gather" in on or "scatter_accum" in on:
+            seen["kernel " + str(stages.get(name))] += 1
+        if re.fullmatch(r"(all-gather|all-reduce|reduce-scatter|all-to-all|"
+                        r"collective-permute)(-start)?", code):
+            seen["collective " + str(stages.get(name))] += 1
+        if code in ("dot", "convolution"):
+            seen["matmul " + str(stages.get(name))] += 1
+    print(json.dumps(seen))
+    """
+)
+
+
+def test_mesh_step_puts_its_exchange_and_backprop_in_stages():
+    """In the sharded round that ``MeshTrainer`` compiles (four fake CPU
+    devices, in a subprocess), every collective falls in
+    ``transport.uplink`` or ``transport.sync``, every operation of the
+    per-leaf RandK kernels in ``transport.uplink``, and every matmul in
+    ``marina.backprop``."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env["PYTHONPATH"] = os.pathsep.join([os.path.join(root, "src"), env.get("PYTHONPATH", "")])
+    r = subprocess.run([sys.executable, "-c", _MESH_STAGES], capture_output=True,
+                       text=True, timeout=300, env=env)
+    assert r.returncode == 0, r.stderr[-4000:]
+    seen = json.loads(r.stdout.strip().splitlines()[-1])
+    stages = {k.split(" ", 1)[0]: set() for k in seen}
+    for k in seen:
+        kind, st = k.split(" ", 1)
+        stages[kind].add(st)
+    assert stages["kernel"] == {"transport.uplink"}, seen
+    assert stages["collective"] == EXCHANGES, seen
+    assert stages["matmul"] == {"marina.backprop"}, seen
