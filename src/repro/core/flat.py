@@ -305,24 +305,27 @@ def block_gather(
 
 
 def block_scatter_mean(
-    values: jax.Array, offsets: jax.Array, block: int, backend: str = "auto"
+    values: jax.Array, offsets: jax.Array, block: int, backend: str = "auto",
+    into: jax.Array | None = None,
 ) -> jax.Array:
     """Scatter-accumulate mean over workers: (n, nblk, kb) ×2 → (nblk, block).
 
     The only dense buffer is the single (nblk, block) accumulator — the n
-    worker payloads stay ζ-sized (never densified per worker). Its callers
-    name the stage: ``flat.compress`` in the engine, ``transport.uplink`` in
-    the mesh transport.
+    worker payloads stay ζ-sized (never densified per worker). With
+    ``into`` (nblk, block) the result is ``into + mean``, added in the same
+    sweep. Its callers name the stage: ``flat.compress`` in the engine,
+    ``transport.uplink`` in the mesh transport.
     """
     backend = resolve_backend(backend)
     if backend == "ref":
         from repro.kernels import ref
 
-        return ref.scatter_accum_ref(values, offsets, block)
+        return ref.scatter_accum_ref(values, offsets, block, into)
     from repro.kernels.randk import scatter_accum
 
     return scatter_accum(
-        values, offsets, block, interpret=(backend == "pallas_interpret")
+        values, offsets, block, into=into,
+        interpret=(backend == "pallas_interpret"),
     )
 
 

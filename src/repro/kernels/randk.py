@@ -94,13 +94,37 @@ def _scatter_accum_kernel(vals_ref, off_ref, out_ref):
     out_ref[...] = (scatter_rows(vals_ref, off_ref, R, B) / n).astype(out_ref.dtype)
 
 
+def _scatter_accum_into_kernel(vals_ref, off_ref, into_ref, out_ref):
+    """``into + mean``: the mean rounded to the payload dtype, then to
+    into's, then one add — what adding the dense mean after the kernel
+    does."""
+    R, B = out_ref.shape
+    n = vals_ref.shape[0]
+    mean = (scatter_rows(vals_ref, off_ref, R, B) / n).astype(vals_ref.dtype)
+    into = into_ref[...]
+    out_ref[...] = (
+        into.astype(jnp.float32) + mean.astype(into.dtype).astype(jnp.float32)
+    ).astype(out_ref.dtype)
+
+
 def scatter_accum(
-    values: jax.Array, offsets: jax.Array, block: int, *, interpret: bool
+    values: jax.Array, offsets: jax.Array, block: int, *,
+    into: jax.Array | None = None, interpret: bool,
 ) -> jax.Array:
-    """values/offsets (n, nblk, kb) → dense (nblk, block) mean over workers."""
+    """values/offsets (n, nblk, kb) → dense (nblk, block) mean over workers,
+    or ``into + mean`` with ``into`` (nblk, block) given: the destination is
+    read tile by tile in the same sweep and written over in place, and the
+    dense mean never reaches memory."""
+    args = [values, offsets.astype(jnp.int32)]
+    kernel, dtype, aliases = _scatter_accum_kernel, values.dtype, None
+    if into is not None:
+        args.append(into)
+        # in place: the compiler need not copy an operand the call reads
+        # into a buffer it may write
+        kernel, dtype, aliases = _scatter_accum_into_kernel, into.dtype, {2: 0}
     (out,) = row_call(
-        _scatter_accum_kernel, [values, offsets.astype(jnp.int32)],
-        [(block, values.dtype)], name="scatter_accum", interpret=interpret,
+        kernel, args, [(block, dtype)], name="scatter_accum",
+        interpret=interpret, aliases=aliases,
     )
     return out
 

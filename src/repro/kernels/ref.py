@@ -22,14 +22,17 @@ def randk_block_compress_ref(x2d: jax.Array, offsets: jax.Array, scale: float) -
 
 
 def scatter_accum_ref(
-    values: jax.Array, offsets: jax.Array, block: int
+    values: jax.Array, offsets: jax.Array, block: int,
+    into: jax.Array | None = None,
 ) -> jax.Array:
     """Server-side aggregation: mean over n workers of scatter-add payloads.
 
     values:  (n, nblk, kb)
     offsets: (n, nblk, kb) local indices in [0, block)
+    into:    optional (nblk, block) destination the mean is added to
     returns: (nblk, block) dense mean; duplicates within a worker accumulate
-             (with-replacement sampling is allowed).
+             (with-replacement sampling is allowed). With ``into``,
+             ``into + mean`` in into's dtype.
     """
     n, nblk, kb = values.shape
     out = jnp.zeros((nblk, block), values.dtype)
@@ -40,7 +43,9 @@ def scatter_accum_ref(
         return dense.at[offs_b.reshape(-1)].add(vals_b.reshape(-1))
 
     dense = jax.vmap(per_block, in_axes=(1, 1))(values, offsets)  # (nblk, block)
-    return dense / n
+    if into is None:
+        return dense / n
+    return into + (dense / n).astype(into.dtype)
 
 
 #: counter offset separating the composition's dither stream from the index

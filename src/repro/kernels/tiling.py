@@ -80,13 +80,15 @@ class Whole:
         self.array = array
 
 
-def row_call(kernel, args, outs, *, name: str, interpret: bool):
+def row_call(kernel, args, outs, *, name: str, interpret: bool,
+             aliases: dict | None = None):
     """pallas_call ``name`` over row tiles of the block axis.
 
     ``args`` are 2-D ``(nblk, w)`` operands, tiled (R, w); 3-D worker stacks
     ``(n, nblk, w)``, tiled (n, R, w) — all workers in every step; or
     :class:`Whole` operands. ``outs`` lists each ``(nblk, width)`` output's
-    (width, dtype)."""
+    (width, dtype). ``aliases`` maps an operand's index to the output that
+    is written over it, tile for tile (``input_output_aliases``)."""
     arrays = [a.array if isinstance(a, Whole) else a for a in args]
     tiled = [a for a in args if not isinstance(a, Whole)]
     nblk = tiled[0].shape[-2]
@@ -109,6 +111,7 @@ def row_call(kernel, args, outs, *, name: str, interpret: bool):
         in_specs=[spec(a) for a in args],
         out_specs=[pl.BlockSpec((R, w), lambda i: (i, 0)) for w, _ in outs],
         out_shape=[jax.ShapeDtypeStruct((nblk, w), dt) for w, dt in outs],
+        input_output_aliases=aliases or {},
         interpret=interpret,
         name=name,
     )(*arrays)

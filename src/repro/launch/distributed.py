@@ -296,17 +296,25 @@ def build_train_steps(
         if faults is not None and faults.attack == "drop" else None
     )
 
-    def compressed_delta(key, diffs):
+    def compressed_g(key, g, diffs):
+        """g^{k+1}: g^k plus the round's compressed mean δ."""
         k_up, k_down = jax.random.split(key)
         k_up = k_up if downlink != "none" else key
         if robust:
             delta = robust_delta(k_up, diffs, n)
+        elif downlink == "none":
+            # the scatter kernels add δ straight into g, so no dense δ is
+            # written; the downlink books the same dense broadcast
+            return transport.downlink(k_down, transport.uplink_mean(
+                k_up, diffs, out_shardings=p_shard,
+                uploaded_rows=drop_uploaded, into=g,
+            ))
         else:
             delta = transport.uplink_mean(
                 k_up, diffs, out_shardings=p_shard,
                 uploaded_rows=drop_uploaded,
             )
-        return transport.downlink(k_down, delta)
+        return add_delta(g, transport.downlink(k_down, delta))
 
     if grad_carry:
         # single-backprop rounds: the carry holds h_i^k = ∇f_i(x^k), so the
@@ -330,7 +338,7 @@ def build_train_steps(
                 faults, jax.random.fold_in(key, _FAULT_FOLD), g_plus, h,
                 jnp.arange(n), n,
             )
-            g_new = add_delta(g, compressed_delta(key, diffs))
+            g_new = compressed_g(key, g, diffs)
             # dropped rows keep their old h (the server never heard from
             # them); c_k=False — this IS the compressed branch
             h_new = _carry_refresh(h, g_plus, faults, jnp.asarray(False), n)
@@ -360,7 +368,7 @@ def build_train_steps(
                 faults, jax.random.fold_in(key, _FAULT_FOLD), g_plus, g_minus,
                 jnp.arange(n), n,
             )
-            g_new = add_delta(g, compressed_delta(key, diffs))
+            g_new = compressed_g(key, g, diffs)
             return x_new, g_new
 
         def train_step(params, g, batch, key):

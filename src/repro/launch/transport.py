@@ -97,10 +97,11 @@ def _gather_along_last(x3d, idx3d, scale, backend):
     return out.reshape(n_, R, kb)
 
 
-def _scatter_mean_last(vals3d, idx3d, L, backend):
-    """(n_eff, R, kb) scatter-accumulate mean over workers → (R, L) f32."""
+def _scatter_mean_last(vals3d, idx3d, L, backend, into=None):
+    """(n_eff, R, kb) scatter-accumulate mean over workers → (R, L) f32, or
+    ``into + mean`` given the (R, L) destination."""
     return flat_engine.block_scatter_mean(
-        vals3d.astype(jnp.float32), idx3d, L, backend
+        vals3d.astype(jnp.float32), idx3d, L, backend, into
     )
 
 
@@ -282,12 +283,14 @@ class Transport:
             (spec, spec), spec,
         )(x3d, idx3d)
 
-    def _scatter_mean(self, vals3d, idx3d, L: int):
-        """Replicated scatter-accumulate mean of (n, R, kb) payloads."""
+    def _scatter_mean(self, vals3d, idx3d, L: int, into=None):
+        """Replicated scatter-accumulate mean of (n, R, kb) payloads, added
+        to ``into`` (R, L), whole on every device, when given."""
+        args = (vals3d, idx3d) if into is None else (vals3d, idx3d, into)
         return self._on_devices(
-            lambda v, i: _scatter_mean_last(v, i, L, self.backend),
-            (P(), P()), P(),
-        )(vals3d, idx3d)
+            lambda *a: _scatter_mean_last(*a[:2], L, self.backend, *a[2:]),
+            (P(),) * len(args), P(),
+        )(*args)
 
     # -- sync exchange ------------------------------------------------------
 
@@ -342,8 +345,10 @@ class Transport:
         out_shardings: Optional[PyTree] = None,
         rows_sharded: bool = True,
         uploaded_rows: Optional[int] = None,
+        into: Optional[PyTree] = None,
     ) -> PyTree:
-        """Per-leaf compressed exchange across workers → dense mean update.
+        """Per-leaf compressed exchange across workers → dense mean update,
+        or ``into + mean`` when ``into`` (a tree like the mean) is given.
 
         Layout: each leaf (rows, *shape) is treated as (rows, R, L) with L
         its last dimension — gathers and scatters act along L only, so they
@@ -384,6 +389,12 @@ class Transport:
         dropped/crashed clients ride the collective as zero rows for shape
         stability, but only the surviving uploads bill (DESIGN.md §4.10:
         booked uplink == arrived·ζ_Q, mirroring the PP r·ζ_Q convention).
+
+        ``into``: the randk and shared-mask scatter kernels add the mean
+        straight into each leaf of ``into`` whose destination sharding
+        (``out_shardings``) is whole on every device, so no dense mean is
+        written; permk, qsgd and sharded destinations add after the mean.
+        The sum and the booked bits are the same either way.
         """
         n = self.n if rows_n is None else rows_n
         if uploaded_rows is not None and not 0 <= uploaded_rows <= n:
@@ -403,15 +414,26 @@ class Transport:
             jax.tree.leaves(out_shardings) if out_shardings is not None
             else [None] * len(leaves)
         )
+        into_leaves = (
+            jax.tree.leaves(into) if into is not None else [None] * len(leaves)
+        )
         keys = jax.random.split(key, len(leaves))
         outs = []
-        for lk, leaf, osh in zip(keys, leaves, out_shard_leaves):
+        for lk, leaf, osh, dst in zip(keys, leaves, out_shard_leaves, into_leaves):
             shape = leaf.shape[1:]
             L = int(shape[-1])
             R = int(np.prod(shape[:-1])) if len(shape) > 1 else 1
             kb = max(1, L // 128)
             scale = L / kb
             x = leaf.reshape(n, R, L)
+            # a destination whole on every device rides into the scatter
+            # kernel, which adds the mean to it in the same sweep
+            into_rows = (
+                dst.reshape(R, L)
+                if dst is not None and dst.dtype == leaf.dtype
+                and osh is not None and osh.is_fully_replicated else None
+            )
+            added = False
 
             wspec = P(waxes if len(waxes) != 1 else waxes[0]) if waxes else P()
             worker_sharded = NamedSharding(self.mesh, wspec)
@@ -494,8 +516,9 @@ class Transport:
                 book_up("psum", _arr_bits(vals) / self.n)
                 vals_mean = jnp.mean(vals, axis=0)                # (R, kb)
                 dense = self._scatter_mean(
-                    vals_mean[None], idx[None], L
+                    vals_mean[None], idx[None], L, into_rows
                 ).astype(leaf.dtype)
+                added = into_rows is not None
             else:
                 idx = jax.random.randint(lk, (n, R, kb), 0, L, jnp.int32)
                 vals = self._gather(
@@ -528,7 +551,10 @@ class Transport:
                     book_up("all-gather", _arr_bits(vals, idx) / self.n)
                     vals = jax.lax.with_sharding_constraint(vals, repl)
                     idx = jax.lax.with_sharding_constraint(idx, repl)
-                dense = self._scatter_mean(vals, idx, L).astype(leaf.dtype)
+                dense = self._scatter_mean(
+                    vals, idx, L, into_rows
+                ).astype(leaf.dtype)
+                added = into_rows is not None
 
             out = dense.reshape(shape)
             if osh is not None and staged:
@@ -537,6 +563,8 @@ class Transport:
                 # materialize the scatter replicated (a 435 GB buffer for
                 # the 671B expert stack)
                 out = jax.lax.with_sharding_constraint(out, osh)
+            if dst is not None and not added:
+                out = jnp.add(dst, out)
             outs.append(out)
         return jax.tree.unflatten(treedef, outs)
 

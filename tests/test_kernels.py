@@ -218,3 +218,36 @@ def test_row_tiles_cover_a_partial_last_tile(kernel):
                                        rtol=1e-5, atol=1e-6)
         else:
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("n", [1, 4])
+@pytest.mark.parametrize(
+    "nblk,B,dup",
+    [(3, 128, False), (5, 1024, True), (40, 2816, False)],
+    ids=["w128", "w1024-dup", "w2816-partial-tile"],
+)
+def test_scatter_accum_into_adds_the_mean_bit_for_bit(n, nblk, B, dup):
+    """``scatter_accum(..., into=g)`` is ``g + scatter_accum(...)`` to the
+    bit, kernel and oracle alike: duplicate offsets accumulate, and at width
+    2816 the 40 rows leave a partial last row tile."""
+    from repro.kernels.tiling import lane_bytes, row_tile
+
+    kb = B // 128
+    key = jax.random.PRNGKey(B + n)
+    values = jax.random.normal(key, (n, nblk, kb), jnp.float32)
+    hi = 2 if dup else B  # two coordinates a row: every draw collides
+    offsets = jax.random.randint(
+        jax.random.fold_in(key, 1), (n, nblk, kb), 0, hi).astype(jnp.int32)
+    g = jax.random.normal(jax.random.fold_in(key, 2), (nblk, B), jnp.float32)
+    if B == 2816:
+        R = row_tile(nblk, n * 2 * lane_bytes(kb, 4) + 2 * lane_bytes(B, 4))
+        assert nblk > R and nblk % R, "shape must leave a partial tile"
+    got = scatter_accum(values, offsets, B, into=g, interpret=True)
+    want = g + scatter_accum(values, offsets, B, interpret=True)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    got_ref = ref.scatter_accum_ref(values, offsets, B, g)
+    want_ref = g + ref.scatter_accum_ref(values, offsets, B)
+    np.testing.assert_array_equal(np.asarray(got_ref), np.asarray(want_ref))
+    # kernel and oracle agree on the sum as they do on the mean
+    np.testing.assert_allclose(np.asarray(got), np.asarray(got_ref),
+                               rtol=1e-6, atol=1e-6)

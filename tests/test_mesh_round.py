@@ -6,6 +6,7 @@ refuse, and the training CLI's ``--backend mesh``."""
 
 import json
 import os
+import re
 import subprocess
 import sys
 import textwrap
@@ -49,6 +50,83 @@ _READINGS = textwrap.dedent(
     print(json.dumps(out))
     """
 )
+
+
+_COMPOSED = textwrap.dedent(
+    """
+    import os, sys
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    sys.path[:0] = [{src!r}]
+    import dataclasses, json, re
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from repro.configs import get_arch
+    from repro.core.marina import _per_worker_grads, _round_keys
+    from repro.models import init_params, lm_loss, reduced
+    from repro.train.mesh import MeshTrainer
+
+    arch = get_arch("qwen1.5-0.5b")
+    # lane-aligned widths, so that the Pallas kernels take every leaf
+    cfg = dataclasses.replace(reduced(arch.model, layers=2, d_model=128), d_ff=384)
+    gamma, p = 0.05, 1e-9   # every round after the first compressed
+    tr = MeshTrainer(dataclasses.replace(arch, model=cfg), batch_per_worker=1,
+                     seq_len=32, gamma=gamma, seed=5, p=p,
+                     backend="pallas_interpret")
+    state = tr.init(lambda: init_params(jax.random.PRNGKey(0), cfg))
+    transport, p_shard = tr.bundle.transport, tr.bundle.param_shardings
+    rcfg = dataclasses.replace(cfg, remat=True)
+    grad_one = jax.grad(lambda x, b: lm_loss(x, rcfg, b["tokens"], b.get("prefix")))
+
+    @jax.jit
+    def composed(x, g, h, batch, key):
+        _, (k_q,), _, _ = _round_keys(key, p)
+        x_new = jax.tree.map(lambda w, gg: w - gamma * gg.astype(w.dtype), x, g)
+        grads = _per_worker_grads(grad_one, x_new, batch)
+        diffs = jax.tree.map(jnp.subtract, grads, h)
+        mean = transport.uplink_mean(k_q, diffs, out_shardings=p_shard)
+        return x_new, jax.tree.map(jnp.add, g, mean), grads
+
+    want = jax.tree.map(np.asarray, state)
+    equal = []
+    for k in range(1, 4):
+        batch, key = tr._inputs(np.int32(k))
+        want = jax.tree.map(np.asarray, composed(*want, batch, key))
+        state = tr.step(state, k)
+        got = jax.tree.map(np.asarray, state)
+        equal.append([all(np.array_equal(a, b) for a, b in
+                          zip(jax.tree.leaves(gi), jax.tree.leaves(wi)))
+                      for gi, wi in zip(got, want)])
+    stages = tr.chunk_stages(state)
+    update = sorted(i for i, s in stages.items() if s == "marina.update")
+    print(json.dumps({{"equal": equal, "update": update,
+                       "compressed": [not bool(c) for c in tr.sync_rounds(range(1, 4))]}}))
+    """
+)
+
+
+@pytest.fixture(scope="module")
+def composed():
+    r = _run([sys.executable, "-c", _COMPOSED.format(src=str(ROOT / "src"))])
+    assert r.returncode == 0, r.stderr[-4000:]
+    return json.loads(r.stdout.strip().splitlines()[-1])
+
+
+def test_mesh_round_matches_its_explicit_composition(composed):
+    """Three compressed rounds of the grad-carry mesh round, the Pallas
+    kernels in interpret mode, give the (x, g, h) of descend → grads →
+    grads − h → g + uplink_mean(...) bit for bit: adding the mean inside the
+    scatter kernel changes no bit of g."""
+    assert composed["compressed"] == [True, True, True]
+    assert composed["equal"] == [[True, True, True]] * 3, composed["equal"]
+
+
+def test_mesh_round_update_stage_holds_only_the_descent(composed):
+    """``marina.update`` holds x − γ·g and no add over g: the mean goes into
+    g inside ``scatter_accum`` (stage ``transport.uplink``)."""
+    update = composed["update"]
+    assert any(i.startswith(("sub", "multiply_subtract")) for i in update), update
+    assert not [i for i in update if re.match(r"add\b|add[._]", i)], update
 
 
 def _run(args, **env):

@@ -208,3 +208,20 @@ def test_fused_qsgd_round_maps_its_kernels_to_their_stages(one_chip, nblk):
     for kernel, stage in want.items():
         got = {st for i, st in stages.items() if re.fullmatch(rf"{kernel}(\.\d+)?", i)}
         assert got == {stage}, (kernel, got)
+
+
+@pytest.mark.parametrize("rows,L", [(151936, 1024), (24 * 1024, 2816)],
+                         ids=["embed", "w_gate"])
+def test_mesh_scatter_into_compiles_at_qwen_leaf_widths(one_chip, rows, L):
+    """The mesh uplink's scatter-mean that adds into g, at qwen1.5-0.5b's
+    embedding and ``w_gate`` leaves (four workers, L/128 offsets a row):
+    it compiles, and writes its result over g (the call aliases its third
+    operand)."""
+    kb = L // 128
+    hlo = _compile(
+        one_chip, lambda v, o, g: scatter_accum(v, o, L, into=g, interpret=False),
+        ((4, rows, kb), f32), ((4, rows, kb), i32), ((rows, L), f32),
+        kernel="scatter_accum",
+    )
+    call = re.search(r"^.*%scatter_accum(\.\d+)? = .* custom-call\(.*$", hlo, re.M)
+    assert "output_to_operand_aliasing={{}: (2, {})}" in call.group(0)
